@@ -58,6 +58,8 @@ def _exec_worker() -> None:
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={N}").strip()
+    # a CPU placeholder mesh: on a TPU host it must not take the chips
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import time
 
     import numpy as np
@@ -67,7 +69,7 @@ def _exec_worker() -> None:
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import primitives
-    from repro.jaxcompat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((N,), ("x",))
     out: dict[str, dict] = {}
@@ -99,10 +101,10 @@ def _exec_worker() -> None:
                                    concat_axis=0)[:, 0]
             return r[None]
 
-        mine = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"),
-                                 out_specs=P("x")))
-        ref = jax.jit(shard_map(g, mesh=mesh, in_specs=P("x"),
-                                out_specs=P("x")))
+        mine = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                                     out_specs=P("x")))
+        ref = jax.jit(jax.shard_map(g, mesh=mesh, in_specs=P("x"),
+                                    out_specs=P("x")))
         got = np.asarray(mine(x))
         want = np.asarray(ref(x))
         if kind in ("reduce_scatter", "all_reduce"):

@@ -74,7 +74,7 @@ def main():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.comms import pccl_all_gather
-    from repro.jaxcompat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     n = len(topo.npus)
     if jax.device_count() >= n:
@@ -84,8 +84,8 @@ def main():
         def run_ag(xl):
             return pccl_all_gather(xl[0], "x", topo, req)[None]
 
-        step = jax.jit(shard_map(run_ag, mesh=jmesh,
-                                 in_specs=P("x"), out_specs=P("x")))
+        step = jax.jit(jax.shard_map(run_ag, mesh=jmesh,
+                                     in_specs=P("x"), out_specs=P("x")))
         out = np.asarray(step(x))  # [n, group_size, 1]
         m = req.group[0]
         print(f"\nexecuted on {n} jax devices: NPU {m} gathered "

@@ -45,7 +45,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.checkpoint import Checkpointer  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.data.pipeline import DataPipeline  # noqa: E402
-from repro.jaxcompat import make_mesh, shard_map_unchecked  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.launch.sharding import ShardingPolicy  # noqa: E402
 from repro.models import LM  # noqa: E402
 from repro.optim import adamw_init, adamw_update, cosine_schedule  # noqa: E402
@@ -121,9 +121,11 @@ def build_dp_step(lm, lr, mesh, dp: int, collectives: str):
         params, opt, om = adamw_update(params, grads, opt, lr=lr)
         return params, opt, loss_mean, om["grad_norm"]
 
-    step = shard_map_unchecked(f, mesh=mesh,
-                               in_specs=(P(), P(), P("data")),
-                               out_specs=(P(), P(), P(), P()))
+    # the ppermute-built all-reduce returns values the replication
+    # checker cannot infer as replicated
+    step = jax.shard_map(f, mesh=mesh,
+                         in_specs=(P(), P(), P("data")),
+                         out_specs=(P(), P(), P(), P()), check_vma=False)
     return jax.jit(step)
 
 

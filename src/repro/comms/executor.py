@@ -1,9 +1,11 @@
 """Execute PCCL-synthesized schedules as shard_map ppermute programs.
 
 This is the TPU adaptation of the paper's §4.8 (MSCCL translation): each
-synthesis wave becomes one `jax.lax.ppermute` over the device mesh. Because
-the synthesizer emits congestion-free neighbor-link transfers, the resulting
-permutes are ICI-neighbor permutes on the physical torus.
+synthesis wave becomes one `jax.lax.ppermute` over the device mesh. The
+synthesizer emits congestion-free neighbor-link transfers, so the permutes
+are ICI-neighbor permutes when mesh device i is the chip at the fabric
+position of NPU i (``chip_smoke.py --chips 4`` places a 2x2 v5e host by
+chip coordinates and checks every permute pair is one hop).
 
 Buffers are functional: every device holds a [num_slots, chunk_elems] array.
 A static *buffer plan* assigns, per device, a slot to every chunk the device

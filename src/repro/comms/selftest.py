@@ -1,13 +1,16 @@
 """Multi-device numerical selftest of the PCCL ppermute executor.
 
-Run as a subprocess (it forces 8 host devices, which must happen before jax
-initializes): ``python -m repro.comms.selftest``. Exit code 0 = all
-collectives bit-match their jax.lax references.
+Run as a subprocess (it forces 8 host-CPU devices, which must happen before
+jax initializes): ``python -m repro.comms.selftest``. Exit code 0 = all
+collectives bit-match their jax.lax references. The CPU platform is forced
+too: on a TPU host the process would otherwise take the chips and find too
+few devices for its 8-device mesh.
 """
 
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np  # noqa: E402
 
@@ -23,7 +26,7 @@ from repro.comms.primitives import (  # noqa: E402
     pccl_all_to_all,
     pccl_reduce_scatter,
 )
-from repro.jaxcompat import make_mesh, shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.topology import line, ring, torus2d  # noqa: E402
 
 
@@ -48,7 +51,8 @@ def test_all_gather_ring():
         def f(xl):
             return pccl_all_gather(xl[0], "x", topo, spec)
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"))(x)
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                             out_specs=P("x"))(x)
 
     got = run(x)  # [8 devices, 8 chunks, 4] -> every device row == full x
     want = jnp.broadcast_to(x, (8, 8, 4)).reshape(8 * 8, 4)
@@ -69,7 +73,8 @@ def test_all_gather_subgroup_with_forwarding():
         def f(xl):
             return pccl_all_gather(xl[0], "x", topo, spec)
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"))(x)
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                             out_specs=P("x"))(x)
 
     got = np.asarray(run(x)).reshape(8, 3, 2)
     want = np.asarray(x)[list(group)]
@@ -90,7 +95,7 @@ def test_all_reduce():
             ref = lax.psum(xl[0], "x")
             return mine[None], ref[None]
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"),
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
                              out_specs=(P("x"), P("x")))(x)
 
     mine, ref = run(x)
@@ -110,7 +115,7 @@ def test_reduce_scatter():
             ref = lax.psum_scatter(xl[0], "x", scatter_dimension=0, tiled=False)
             return mine[None], ref[None]
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"),
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
                              out_specs=(P("x"), P("x")))(x)
 
     mine, ref = run(x)
@@ -132,7 +137,7 @@ def test_all_to_all_torus_rows():
                                  concat_axis=0)[:, 0]
             return mine[None], ref[None]
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"),
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
                              out_specs=(P("x"), P("x")))(x)
 
     mine, ref = run(x)
@@ -152,7 +157,8 @@ def test_all_to_all_subgroup():
         def f(xl):
             return pccl_all_to_all(xl[0], "x", topo, spec)[None]
 
-        return shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x"))(x)
+        return jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                             out_specs=P("x"))(x)
 
     got = np.asarray(run(x))
     xs = np.asarray(x)
@@ -174,7 +180,7 @@ def test_two_axis_flattened():
         def f(xl):
             return pccl_all_gather(xl[0], ("r", "c"), topo, spec)[None]
 
-        return shard_map(f, mesh=mesh, in_specs=P(("r", "c")),
+        return jax.shard_map(f, mesh=mesh, in_specs=P(("r", "c")),
                              out_specs=P(("r", "c")))(x)
 
     got = np.asarray(run(x)).reshape(8, 8, 2)

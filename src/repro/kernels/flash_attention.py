@@ -100,7 +100,7 @@ def flash_attention(
     softcap: float = 0.0,
     block_q: int = 512,
     block_kv: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]

@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile natively; elsewhere they run in interpret mode
-(the kernel body executes as jax ops — bit-faithful to the TPU tiling but
-slow), which is how the CPU test suite validates them against the ref.py
-oracles. The model layer calls these through `use_flash`/`use_kernel` flags.
+On TPU the kernels compile natively (tests/test_tpu_compile.py compiles
+both at model widths for a described v5e chip); elsewhere they run in
+interpret mode (the kernel body executes as jax ops — bit-faithful to the
+TPU tiling but slow), which is how the CPU test suite validates them
+against the ref.py oracles. The model layer calls these through
+`use_flash`/`use_kernel` flags.
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
+def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, o_ref, state_scr, *,
                 chunk):
     c_idx = pl.program_id(2)
 
@@ -31,38 +31,44 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_scr, *,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)  # [Q, P]
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # [Q]
-    A = a_ref[0].astype(jnp.float32)  # []
+    x = x_ref[0, 0].astype(jnp.float32)  # [Q, P]
+    dt = dt_ref[0, 0].astype(jnp.float32)  # [Q, 1]
+    dA = da_ref[0, 0].astype(jnp.float32)  # [Q, 1] = dt * A (A < 0)
     Bm = b_ref[0].astype(jnp.float32)  # [Q, N]
     Cm = c_ref[0].astype(jnp.float32)  # [Q, N]
 
-    dA = dt * A  # [Q] (A < 0)
-    csum = jnp.cumsum(dA)  # [Q]
-    total = csum[-1]
-    xdt = x * dt[:, None]  # [Q, P]
+    # prefix sums of dA as a column and as a row, by masked reductions over
+    # [Q, Q] (no 1-D vectors, cumsum or transposes in the kernel body)
+    ii = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dA_row = jnp.sum(jnp.where(ii == jj, dA, 0.0), axis=0,
+                     keepdims=True)  # [1, Q]
+    csum_col = jnp.sum(jnp.where(jj <= ii, dA_row, 0.0), axis=1,
+                       keepdims=True)  # [Q, 1]
+    csum_row = jnp.sum(jnp.where(ii <= jj, dA, 0.0), axis=0,
+                       keepdims=True)  # [1, Q]
+    total = jnp.sum(dA_row, axis=1, keepdims=True)  # [1, 1]
+    xdt = x * dt  # [Q, P]
 
     # intra-chunk: (C B^T ∘ L) @ (x*dt), L[i,j] = exp(csum_i - csum_j), i>=j
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [Q,Q]
-    ii = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(ii >= jj, jnp.exp(csum[:, None] - csum[None, :]), 0.0)
+    L = jnp.where(ii >= jj, jnp.exp(csum_col - csum_row), 0.0)
     intra = jax.lax.dot_general(scores * L, xdt, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [Q,P]
 
     # inter-chunk: C_i decay_i @ state_in^T  (state: [P, N])
     state = state_scr[...]
-    decayed_C = Cm * jnp.exp(csum)[:, None]  # [Q, N]
+    decayed_C = Cm * jnp.exp(csum_col)  # [Q, N]
     inter = jax.lax.dot_general(decayed_C, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [Q,P]
 
-    o_ref[0, :, 0] = (intra + inter).astype(o_ref.dtype)
+    o_ref[0, 0] = (intra + inter).astype(o_ref.dtype)
 
     # state update: exp(total) * state + sum_j exp(total - csum_j) x_j B_j^T
-    decay_to_end = jnp.exp(total - csum)  # [Q]
+    decay_to_end = jnp.exp(total - csum_col)  # [Q, 1]
     dstate = jax.lax.dot_general(
-        xdt * decay_to_end[:, None], Bm, (((0,), (0,)), ((), ())),
+        xdt * decay_to_end, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)  # [P, N]
     state_scr[...] = state * jnp.exp(total) + dstate
 
@@ -77,7 +83,7 @@ def ssd_scan(
     Cm: jax.Array,  # [B, S, N]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, P = xh.shape
     N = Bm.shape[-1]
@@ -87,19 +93,24 @@ def ssd_scan(
     nc = S // Q
     grid = (B, H, nc)
 
+    # heads ahead of the sequence, so each block's last two dims are a
+    # (chunk, width) tile: (Q, P) for x, (Q, 1) for the per-step scalars
+    xr = jnp.moveaxis(xh, 2, 1)  # [B, H, S, P]
+    dtr = jnp.moveaxis(dt, 2, 1)[..., None]  # [B, H, S, 1]
+    dar = dtr * A[None, :, None, None]
+
+    def head_block(width):
+        return pl.BlockSpec((1, 1, Q, width), lambda b, h, c: (b, h, c, 0))
+
+    seq_block = pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0))
     out = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=Q),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, P), xh.dtype),
+        in_specs=[head_block(P), head_block(1), head_block(1), seq_block,
+                  seq_block],
+        out_specs=head_block(P),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(xh, dt, A, Bm, Cm)
-    return out
+    )(xr, dtr, dar, Bm, Cm)
+    return jnp.moveaxis(out, 1, 2)

@@ -1,14 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: .lower().compile() every (architecture x input-shape x
 mesh) cell on the production meshes, and extract the roofline terms from the
 compiled artifacts.
 
-The two lines above MUST stay the first statements of this module — jax locks
+The lines above MUST stay the first statements of this module — jax locks
 the device count at first init, and the dry-run needs 512 placeholder host
-devices to build the (2, 16, 16) production mesh. (Do not import this module
-from tests/benches: they must see 1 device.)
+devices to build the (2, 16, 16) production mesh. The CPU platform is forced
+so that on a TPU host the placeholder mesh never takes the chips. (Do not
+import this module from tests/benches: they must see 1 device.)
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                 # all cells

@@ -8,7 +8,19 @@ and smoke tests/benches must keep seeing 1 device.
 
 from __future__ import annotations
 
-from repro.jaxcompat import make_mesh
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (jax defaults to Explicit): the
+    models steer GSPMD with sharding constraints rather than typed axes.
+    ``devices`` defaults to every local device."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(tuple(axis_names)),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.jaxcompat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import ShardingPolicy, pad_heads
 from repro.models import LM
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.jaxcompat import shard_map
 from repro.models.layers import Params, _init, apply_rope, rope_tables
 
 
@@ -343,8 +342,8 @@ def _seq_parallel_attention(q, k, v, policy, *, causal, window, softcap):
                                    policy=None, offset=off)
 
     spec = P(dp, tp, None, None)
-    return shard_map(local, mesh=policy.mesh, in_specs=(spec,) * 3,
-                     out_specs=spec)(q, k, v)
+    return jax.shard_map(local, mesh=policy.mesh, in_specs=(spec,) * 3,
+                         out_specs=spec)(q, k, v)
 
 
 def attention_train(

@@ -82,10 +82,12 @@ def _ssd_chunked(xh, dt, A, Bm, Cm, chunk: int, policy=None):
     total = csum[:, :, -1, :]  # [B, nc, H] chunk total decay
 
     # ---- intra-chunk (dual quadratic form) ----
-    # L[i, j] = exp(csum_i - csum_j) for i >= j
+    # L[i, j] = exp(csum_i - csum_j) for i >= j. Mask before the exp: above
+    # the diagonal diff > 0 can overflow to inf, and the where's zero
+    # cotangent times an infinite exp derivative would make the grads NaN.
     diff = csum[:, :, :, None, :] - csum[:, :, None, :, :]  # [B,nc,Q,Q,H]
     mask = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(mask[None, None, :, :, None], jnp.exp(diff), 0.0)
+    L = jnp.exp(jnp.where(mask[None, None, :, :, None], diff, -jnp.inf))
     scores = jnp.einsum("bcin,bcjn->bcij", Cc, Bc)  # [B,nc,Q,Q]
     intra = jnp.einsum("bcij,bcijh,bcjhp->bcihp", scores, L, x_)
 

@@ -81,7 +81,7 @@ def run_on_mesh(kind: str, topo, spec, x: np.ndarray, *, n: int = 8,
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import primitives
-    from repro.jaxcompat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     fn = getattr(primitives, f"pccl_{kind}")
     mesh = make_mesh((n,), ("x",))
@@ -91,7 +91,8 @@ def run_on_mesh(kind: str, topo, spec, x: np.ndarray, *, n: int = 8,
                  device_of_npu=device_of_npu)
         return out[None]
 
-    run = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P("x")))
+    run = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                                  out_specs=P("x")))
     return np.asarray(run(x))
 
 

@@ -101,7 +101,7 @@ def test_vs_lax_reference(kind):
     from jax.sharding import PartitionSpec as P
 
     from repro.comms import primitives
-    from repro.jaxcompat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
 
     topo = build_topo("grid23")
     req = request(kind, range(N), hierarchy="always")
@@ -123,8 +123,8 @@ def test_vs_lax_reference(kind):
                                  concat_axis=0)[:, 0]
         return mine[None], ref[None]
 
-    run = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"),
-                            out_specs=(P("x"), P("x"))))
+    run = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                                out_specs=(P("x"), P("x"))))
     mine, ref = run(x)
     assert_conformant(kind, np.asarray(mine), np.asarray(ref),
                       f"{kind} vs lax built-in")

@@ -1,0 +1,111 @@
+"""Compile the main path for TPU v5e without a chip attached.
+
+The TPU compiler is installed wherever libtpu is, and compiles for a
+*described* v5e:2x2 topology: each test lowers a program at real widths for
+the described devices and checks what the compiler put in. Nothing runs, so
+these say nothing about results or times; they catch what interpret mode and
+the CPU backend cannot (tiling, VMEM limits, partitioning).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold libtpu, and pytest-xdist workers all import every
+test file. Keep these tests in this one file so one worker holds it.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.comms import primitives
+from repro.core.registry import AlgorithmRegistry
+from repro.core.request import CollectiveRequest
+from repro.kernels import flash_attention as fa
+from repro.kernels import ssd_scan as ssd
+from repro.launch.sharding import MeshCollectivePlanner
+from repro.topology import mesh2d
+
+KINDS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
+SHARD = 1 << 20  # f32 elements per device
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a persistent cache would store these compiles but could never read
+    # them back without a chip, and warn on every later compile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    """The four chips as one axis in mesh2d(2, 2) NPU order: NPU r*2 + c is
+    the chip at coordinates (x=c, y=r)."""
+    at = {tuple(d.coords[:2]): d for d in topo.devices}
+    return Mesh(np.array([at[(n % 2, n // 2)] for n in range(4)]), ("x",))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_pccl_collective_compiles_to_permutes(kind, mesh):
+    planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
+                                    registry=AlgorithmRegistry())
+    program = planner.program(kind, "x", 0, nbytes=SHARD * 4)
+    spec = CollectiveRequest(kind, group=(0, 1, 2, 3))
+    fn = getattr(primitives, f"pccl_{kind}")
+    shape = (4, SHARD) if kind in ("all_gather", "all_reduce") else (4, 4, SHARD // 4)
+
+    def run(xl):
+        return fn(xl[0], "x", None, spec, program=program)[None]
+
+    x = jax.ShapeDtypeStruct(shape, jnp.float32,
+                             sharding=NamedSharding(mesh, P("x")))
+    compiled = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("x"),
+                                     out_specs=P("x"))).lower(x).compile()
+    hlo = compiled.as_text()
+    assert "collective-permute" in hlo
+    for builtin in ("all-gather", "all-reduce", "reduce-scatter", "all-to-all"):
+        assert f" {builtin}(" not in hlo and f" {builtin}-start(" not in hlo
+
+
+def test_flash_attention_compiles_natively(one_chip):
+    # llama3.2-1b widths: 32 query heads, 8 kv heads, head_dim 64
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.bfloat16, sharding=one_chip)
+    compiled = fa.flash_attention.lower(
+        q, kv, kv, causal=True, block_q=512, block_kv=512,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_scan_compiles_natively(one_chip):
+    # mamba2-370m widths: d_inner 2048 = 32 heads of 64, state 128
+    B, S, H, Pd, N = 1, 4096, 32, 64, 128
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = ssd.ssd_scan.lower(
+        f32(B, S, H, Pd), f32(B, S, H), f32(H), f32(B, S, N), f32(B, S, N),
+        chunk=128, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
