@@ -4,7 +4,6 @@ from repro.comms.executor import (
     execute_program,
     plan_buffers,
     plan_buffers_cached,
-    plan_cache_stats,
 )
 from repro.comms.primitives import (
     CollectiveSpec,
@@ -29,7 +28,6 @@ __all__ = [
     "execute_program",
     "plan_buffers",
     "plan_buffers_cached",
-    "plan_cache_stats",
     "CollectiveSpec",
     "lower_algorithm",
     "pccl_all_gather",

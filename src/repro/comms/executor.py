@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.translate import PpermuteProgram
+from repro.tracing import count, span
 
 
 @dataclass
@@ -164,7 +165,6 @@ def plan_buffers(prog: PpermuteProgram) -> BufferPlan:
 _PLAN_CACHE: OrderedDict[object, BufferPlan] = OrderedDict()
 _PLAN_CACHE_MAX = 128
 _PLAN_LOCK = threading.Lock()
-plan_cache_stats = {"hits": 0, "misses": 0}
 
 
 def plan_buffers_cached(prog: PpermuteProgram, fingerprint: object) -> BufferPlan:
@@ -182,18 +182,19 @@ def plan_buffers_cached(prog: PpermuteProgram, fingerprint: object) -> BufferPla
         plan = _PLAN_CACHE.get(key)
         if plan is not None:
             _PLAN_CACHE.move_to_end(key)
-            plan_cache_stats["hits"] += 1
+            count("plan_cache.hit")
             return plan
     # plan outside the lock: duplicated work under a race is cheaper than
     # serializing every cold plan behind one mutex
-    plan = plan_buffers(prog)
+    with span("pccl.buffers"):
+        plan = plan_buffers(prog)
     with _PLAN_LOCK:
         existing = _PLAN_CACHE.get(key)
         if existing is not None:
             _PLAN_CACHE.move_to_end(key)
-            plan_cache_stats["hits"] += 1
+            count("plan_cache.hit")
             return existing
-        plan_cache_stats["misses"] += 1
+        count("plan_cache.miss")
         _PLAN_CACHE[key] = plan
         while len(_PLAN_CACHE) > _PLAN_CACHE_MAX:
             _PLAN_CACHE.popitem(last=False)
@@ -203,7 +204,6 @@ def plan_buffers_cached(prog: PpermuteProgram, fingerprint: object) -> BufferPla
 def clear_plan_cache() -> None:
     with _PLAN_LOCK:
         _PLAN_CACHE.clear()
-        plan_cache_stats.update(hits=0, misses=0)
 
 
 def execute_program(
@@ -217,14 +217,22 @@ def execute_program(
     idx = lax.axis_index(axis_name)
     send_t, recv_t, reduce_t = plan.round_tables()
     for r, rt in enumerate(plan.rounds):
-        send_slot = send_t[r, idx]
-        recv_slot = recv_t[r, idx]
-        reduce_here = reduce_t[r, idx]
-        val = lax.dynamic_index_in_dim(buf, send_slot, axis=0, keepdims=False)
-        got = lax.ppermute(val, axis_name, rt.perm)
-        old = lax.dynamic_index_in_dim(buf, recv_slot, axis=0, keepdims=False)
-        new = jnp.where(reduce_here, old + got, got)
-        buf = lax.dynamic_update_index_in_dim(buf, new, recv_slot, axis=0)
+        # named scopes tag each stage's ops for the profiler; they change
+        # the ops' metadata only, not the compiled program
+        with jax.named_scope("pccl.send"):  # the round's table reads too
+            send_slot = send_t[r, idx]
+            recv_slot = recv_t[r, idx]
+            reduce_here = reduce_t[r, idx]
+            val = lax.dynamic_index_in_dim(buf, send_slot, axis=0,
+                                           keepdims=False)
+        with jax.named_scope("pccl.permute"):
+            got = lax.ppermute(val, axis_name, rt.perm)
+        with jax.named_scope("pccl.receive"):
+            old = lax.dynamic_index_in_dim(buf, recv_slot, axis=0,
+                                           keepdims=False)
+            new = jnp.where(reduce_here, old + got, got)
+        with jax.named_scope("pccl.update"):
+            buf = lax.dynamic_update_index_in_dim(buf, new, recv_slot, axis=0)
     return buf
 
 

@@ -32,6 +32,7 @@ from repro.core.registry import default_registry, topology_fingerprint
 from repro.core.request import CollectiveRequest
 from repro.core.translate import PpermuteProgram, to_ppermute_program
 from repro.topology.topology import Topology
+from repro.tracing import count, span
 
 
 @dataclass(frozen=True)
@@ -119,23 +120,28 @@ def synthesize_program(
     engine route: ``hierarchy="always"``, TE gateway strategies, comm
     sketches, pipelined all-reduce. ``nbytes``/``pipelined_ar`` only apply
     to the CollectiveSpec form; a request carries its own."""
-    registry = registry if registry is not None else default_registry()
-    req = _as_request(spec, nbytes, pipelined_ar)
-    dev_key = (None if device_of_npu is None
-               else tuple(sorted(device_of_npu.items())))
-    key = (topology_fingerprint(topo), req.fingerprint(), dev_key)
-    prog = _PROGRAM_CACHE.get(key)
-    if prog is not None:
-        _PROGRAM_CACHE.move_to_end(key)
-    else:
-        engine = _engine_for(topo, registry)
-        alg = engine.collective(req)
-        alg.validate()
-        prog = to_ppermute_program(alg, device_of_npu)
-        _PROGRAM_CACHE[key] = prog
-        while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
-            _PROGRAM_CACHE.popitem(last=False)
-    return prog, plan_buffers_cached(prog, key)
+    with span("pccl.plan"):
+        registry = registry if registry is not None else default_registry()
+        req = _as_request(spec, nbytes, pipelined_ar)
+        dev_key = (None if device_of_npu is None
+                   else tuple(sorted(device_of_npu.items())))
+        key = (topology_fingerprint(topo), req.fingerprint(), dev_key)
+        prog = _PROGRAM_CACHE.get(key)
+        if prog is not None:
+            _PROGRAM_CACHE.move_to_end(key)
+            count("program_cache.hit")
+        else:
+            count("program_cache.miss")
+            engine = _engine_for(topo, registry)
+            alg = engine.collective(req)
+            with span("pccl.validate"):
+                alg.validate()
+            with span("pccl.translate"):
+                prog = to_ppermute_program(alg, device_of_npu)
+            _PROGRAM_CACHE[key] = prog
+            while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_MAX:
+                _PROGRAM_CACHE.popitem(last=False)
+        return prog, plan_buffers_cached(prog, key)
 
 
 def lower_algorithm(
@@ -151,10 +157,13 @@ def lower_algorithm(
     via their ``program=`` argument. ``key`` namespaces the buffer-plan
     cache entry; the program's structural digest keeps distinct schedules
     apart even under one key."""
-    if validate:
-        alg.validate()
-    prog = to_ppermute_program(alg, device_of_npu)
-    return prog, plan_buffers_cached(prog, key)
+    with span("pccl.plan"):
+        if validate:
+            with span("pccl.validate"):
+                alg.validate()
+        with span("pccl.translate"):
+            prog = to_ppermute_program(alg, device_of_npu)
+        return prog, plan_buffers_cached(prog, key)
 
 
 def _group_devices(prog: PpermuteProgram, spec,
@@ -220,18 +229,20 @@ def pccl_all_gather(
     for dev in devices:
         (chunk,) = by_src[dev]
         my_chunk_slot[dev] = plan.slot_of[(dev, chunk)]
-    idx = lax.axis_index(axis_name)
-    buf = jnp.zeros((plan.buffer_slots, *x.shape), x.dtype)
-    buf = lax.dynamic_update_index_in_dim(
-        buf, x, jnp.asarray(my_chunk_slot)[idx], axis=0
-    )
+    with jax.named_scope("pccl.place"):
+        idx = lax.axis_index(axis_name)
+        buf = jnp.zeros((plan.buffer_slots, *x.shape), x.dtype)
+        buf = lax.dynamic_update_index_in_dim(
+            buf, x, jnp.asarray(my_chunk_slot)[idx], axis=0
+        )
     buf = execute_program(plan, buf, axis_name)
     ordered_chunks = [by_src[d][0] for d in devices]
-    out = gather_slots(plan, buf, axis_name, ordered_chunks)
-    # non-participants may have forwarded chunks sitting in their slots —
-    # mask so their output is untouched-by-the-collective zeros
-    out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
-    return jnp.concatenate(list(out), axis=0) if tiled else out
+    with jax.named_scope("pccl.gather"):
+        out = gather_slots(plan, buf, axis_name, ordered_chunks)
+        # non-participants may have forwarded chunks sitting in their slots
+        # — mask so their output is untouched-by-the-collective zeros
+        out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        return jnp.concatenate(list(out), axis=0) if tiled else out
 
 
 def pccl_reduce_scatter(
@@ -260,12 +271,13 @@ def pccl_reduce_scatter(
             got = plan.slot_of.get((dev, c))
             if got is not None:
                 init_slot[dev, ci] = got
-    idx = lax.axis_index(axis_name)
-    buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
-    for ci in range(len(chunks)):
-        buf = lax.dynamic_update_index_in_dim(
-            buf, x[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
-        )
+    with jax.named_scope("pccl.place"):
+        idx = lax.axis_index(axis_name)
+        buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
+        for ci in range(len(chunks)):
+            buf = lax.dynamic_update_index_in_dim(
+                buf, x[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
+            )
     buf = execute_program(plan, buf, axis_name)
     # each group device extracts its own chunk
     my_chunk_table = np.zeros(prog.num_devices, dtype=np.int64)
@@ -274,10 +286,11 @@ def pccl_reduce_scatter(
     out_slot = np.full(prog.num_devices, plan.num_slots, np.int32)
     for dev in devices:
         out_slot[dev] = plan.slot_of[(dev, int(my_chunk_table[dev]))]
-    out = lax.dynamic_index_in_dim(
-        buf, jnp.asarray(out_slot)[idx], axis=0, keepdims=False
-    )
-    return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+    with jax.named_scope("pccl.gather"):
+        out = lax.dynamic_index_in_dim(
+            buf, jnp.asarray(out_slot)[idx], axis=0, keepdims=False
+        )
+        return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
 
 
 def pccl_all_reduce(
@@ -299,23 +312,25 @@ def pccl_all_reduce(
     assert len(chunks) == g, "all_reduce uses one shard-chunk per member"
     # chunk order follows group order by construction (see
     # synthesizer.synthesize_all_reduce: reduce_scatter iterates the group)
-    xs = jnp.reshape(x, (g, x.shape[0] // g, *x.shape[1:]))
     init_slot = np.full((prog.num_devices, g), plan.num_slots, np.int32)
     for ci, c in enumerate(chunks):
         for dev in devices:
             got = plan.slot_of.get((dev, c))
             if got is not None:
                 init_slot[dev, ci] = got
-    idx = lax.axis_index(axis_name)
-    buf = jnp.zeros((plan.buffer_slots, *xs.shape[1:]), x.dtype)
-    for ci in range(g):
-        buf = lax.dynamic_update_index_in_dim(
-            buf, xs[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
-        )
+    with jax.named_scope("pccl.place"):
+        xs = jnp.reshape(x, (g, x.shape[0] // g, *x.shape[1:]))
+        idx = lax.axis_index(axis_name)
+        buf = jnp.zeros((plan.buffer_slots, *xs.shape[1:]), x.dtype)
+        for ci in range(g):
+            buf = lax.dynamic_update_index_in_dim(
+                buf, xs[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
+            )
     buf = execute_program(plan, buf, axis_name)
-    out = gather_slots(plan, buf, axis_name, chunks)
-    out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
-    return jnp.reshape(out, x.shape)
+    with jax.named_scope("pccl.gather"):
+        out = gather_slots(plan, buf, axis_name, chunks)
+        out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        return jnp.reshape(out, x.shape)
 
 
 def pccl_all_to_all(
@@ -346,25 +361,28 @@ def pccl_all_to_all(
         recv_chunk_slot[dst, i] = plan.slot_of[(dst, chunk)]
     for dev in devices:
         self_row[dev] = rank_of_device[dev]
-    idx = lax.axis_index(axis_name)
-    buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
-    for j in range(g):
-        buf = lax.dynamic_update_index_in_dim(
-            buf, x[j], jnp.asarray(send_chunk_slot[:, j])[idx], axis=0
-        )
-    buf = execute_program(plan, buf, axis_name)
-    rows = []
-    for i in range(g):
-        rows.append(
-            lax.dynamic_index_in_dim(
-                buf, jnp.asarray(recv_chunk_slot[:, i])[idx], axis=0, keepdims=False
+    with jax.named_scope("pccl.place"):
+        idx = lax.axis_index(axis_name)
+        buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
+        for j in range(g):
+            buf = lax.dynamic_update_index_in_dim(
+                buf, x[j], jnp.asarray(send_chunk_slot[:, j])[idx], axis=0
             )
-        )
-    out = jnp.stack(rows)
-    # self row: take from input (never transferred)
-    me = jnp.asarray(self_row)[idx]
-    self_payload = lax.dynamic_index_in_dim(x, me, axis=0, keepdims=False)
-    out = lax.dynamic_update_index_in_dim(out, self_payload, me, axis=0)
-    # the self-row write above lands row 0 <- x[0] on non-participants
-    # (self_row defaults to 0); mask them back to zeros
-    return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+    buf = execute_program(plan, buf, axis_name)
+    with jax.named_scope("pccl.gather"):
+        rows = []
+        for i in range(g):
+            rows.append(
+                lax.dynamic_index_in_dim(
+                    buf, jnp.asarray(recv_chunk_slot[:, i])[idx], axis=0,
+                    keepdims=False
+                )
+            )
+        out = jnp.stack(rows)
+        # self row: take from input (never transferred)
+        me = jnp.asarray(self_row)[idx]
+        self_payload = lax.dynamic_index_in_dim(x, me, axis=0, keepdims=False)
+        out = lax.dynamic_update_index_in_dim(out, self_payload, me, axis=0)
+        # the self-row write above lands row 0 <- x[0] on non-participants
+        # (self_row defaults to 0); mask them back to zeros
+        return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))

@@ -36,6 +36,7 @@ from repro.core.request import (_UNSET, CollectiveRequest,
                                 PCCLDeprecationWarning)
 from repro.core.ten import TEN
 from repro.topology.topology import Topology
+from repro.tracing import span
 
 
 # ---------------------------------------------------------------------------
@@ -893,11 +894,12 @@ class SynthesisEngine:
         engine's configuration. ``ids`` stays a call-site argument: it is
         the caller's mutable chunk-id allocator, not part of the request's
         identity."""
-        if request.gateway_strategy is None and request.sketch is None:
-            return self._collective(request, ids=ids)
-        return self._configured(
-            request.gateway_strategy, request.sketch
-        )._collective(request, ids=ids)
+        with span("pccl.synthesize"):
+            if request.gateway_strategy is None and request.sketch is None:
+                return self._collective(request, ids=ids)
+            return self._configured(
+                request.gateway_strategy, request.sketch
+            )._collective(request, ids=ids)
 
     def _configured(self, gateway_strategy, sketch) -> "SynthesisEngine":
         """A memoized engine variant with the given overrides (None =

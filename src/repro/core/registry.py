@@ -43,6 +43,7 @@ from repro.core.algorithm import (CollectiveAlgorithm, TransferColumns,
                                   remap_ids)
 from repro.core.conditions import ChunkIds, ReduceCondition
 from repro.topology.topology import Topology
+from repro.tracing import span
 
 # bound on the enumerated symmetry group (torus2d 16x16 translations = 256;
 # the cap only matters for pathological generator sets)
@@ -528,7 +529,8 @@ class AlgorithmRegistry:
                 if alg is not None:
                     self.stats.disk_hits += 1
                 else:
-                    alg = synth(list(canon))
+                    with span("pccl.search"):
+                        alg = synth(list(canon))
                     self.stats.misses += 1
                     self._store_disk(key, alg)
                 self._lru[key] = alg

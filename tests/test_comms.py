@@ -193,17 +193,17 @@ class TestPlanCache:
         assert p4.digest() != p6.digest()
 
     def test_hit_miss_stats(self):
-        from repro.comms import (
-            clear_plan_cache,
-            plan_buffers_cached,
-            plan_cache_stats,
-        )
+        from repro.comms import clear_plan_cache, plan_buffers_cached
+        from repro.tracing import counters
 
         clear_plan_cache()
         p = self._prog(5)
+        before = counters()
         plan_buffers_cached(p, "fp")
         plan_buffers_cached(p, "fp")
-        assert plan_cache_stats == {"hits": 1, "misses": 1}
+        after = counters()
+        assert [after.get(k, 0) - before.get(k, 0)
+                for k in ("plan_cache.hit", "plan_cache.miss")] == [1, 1]
 
     def test_thread_safety_under_eviction_churn(self, monkeypatch):
         """Many threads sharing a tiny cache: every served plan must match

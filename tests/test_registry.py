@@ -360,40 +360,44 @@ class TestJointSynthesis:
 
 class TestCommsPlanCache:
     def test_plan_cache_hit_on_repeat(self):
-        from repro.comms.executor import (
-            clear_plan_cache,
-            plan_buffers_cached,
-            plan_cache_stats,
-        )
+        from repro.comms.executor import clear_plan_cache, plan_buffers_cached
         from repro.core import to_ppermute_program
+        from repro.tracing import counters
 
         clear_plan_cache()
         topo = ring(4, bidirectional=True)
         alg = synthesize_all_gather(topo, list(range(4)))
         prog = to_ppermute_program(alg)
+        before = counters()
         p1 = plan_buffers_cached(prog, "fp-1")
         p2 = plan_buffers_cached(prog, "fp-1")
+        after = counters()
         assert p1 is p2
-        assert plan_cache_stats == {"hits": 1, "misses": 1}
+        assert [after.get(k, 0) - before.get(k, 0)
+                for k in ("plan_cache.hit", "plan_cache.miss")] == [1, 1]
         clear_plan_cache()
 
     def test_synthesize_program_reuses_plan(self):
-        from repro.comms.executor import plan_cache_stats
         from repro.comms.primitives import (
             _PROGRAM_CACHE,
             CollectiveSpec,
             synthesize_program,
         )
+        from repro.tracing import counters
 
         topo = ring(4, bidirectional=True)
         spec = CollectiveSpec("all_gather", (0, 1, 2, 3))
         reg = AlgorithmRegistry()
         prog1, plan1 = synthesize_program(topo, spec, registry=reg)
-        before = dict(plan_cache_stats)
+        before = counters()
         # repeated identical collective: plan served from the executor cache
         prog2, plan2 = synthesize_program(topo, spec, registry=reg)
         assert plan2 is plan1 and prog2 is prog1
-        assert plan_cache_stats["hits"] == before["hits"] + 1
+        after = counters()
+        assert (after["plan_cache.hit"]
+                == before.get("plan_cache.hit", 0) + 1)
+        assert (after["program_cache.hit"]
+                == before.get("program_cache.hit", 0) + 1)
         # even after the program cache is dropped, the plan survives
         _PROGRAM_CACHE.clear()
         _, plan3 = synthesize_program(topo, spec, registry=reg)
