@@ -7,7 +7,8 @@ are ICI-neighbor permutes when mesh device i is the chip at the fabric
 position of NPU i (``chip_smoke.py --chips 4`` places a 2x2 v5e host by
 chip coordinates and checks every permute pair is one hop).
 
-Buffers are functional: every device holds a [num_slots, chunk_elems] array.
+Buffers are functional: every device holds a [num_slots, *chunk_shape]
+array, a 1-D chunk that fills whole TPU tiles as rows of 128 (`slot_rows`).
 A static *buffer plan* assigns, per device, a slot to every chunk the device
 ever holds (source, in-transit forwarder — possibly outside the process
 group, which is how PG-awareness executes — or destination). Slot lookups
@@ -204,6 +205,34 @@ def plan_buffers_cached(prog: PpermuteProgram, fingerprint: object) -> BufferPla
 def clear_plan_cache() -> None:
     with _PLAN_LOCK:
         _PLAN_CACHE.clear()
+
+
+# TPU memory holds a 2-D array in tiles of (sublanes, 128) over its two minor
+# dims, 32 bytes of sublanes per lane; a 1-D array sits in runs of
+# sublanes * 128 elements, which are byte for byte one such tile of rows.
+_LANES = 128
+_SUBLANES = {4: 8, 2: 16, 1: 32}  # by itemsize
+
+
+def slot_rows(chunk_shape: tuple[int, ...], dtype) -> tuple[int, ...]:
+    """The shape a chunk of ``chunk_shape`` takes in a slot buffer.
+
+    A 1-D chunk whose length fills whole tiles is stored as rows of 128
+    lanes, so the buffer ``[slots, C // 128, 128]`` keeps each slot a
+    contiguous run of whole tiles and the reshape from and to the chunk is
+    a bitcast. Stored flat, the slot axis would be the second-minor dim:
+    each slot a strided stripe through every tile, and the chunks relaid
+    at placement. Every other chunk keeps its shape, since rows would need
+    padding, which is copies. Counts ``slot_layout.rows`` or
+    ``slot_layout.flat`` once per call; the primitives call it once per
+    traced collective."""
+    sublanes = _SUBLANES.get(jnp.dtype(dtype).itemsize)
+    if (len(chunk_shape) == 1 and sublanes is not None and chunk_shape[0]
+            and chunk_shape[0] % (_LANES * sublanes) == 0):
+        count("slot_layout.rows")
+        return (chunk_shape[0] // _LANES, _LANES)
+    count("slot_layout.flat")
+    return tuple(chunk_shape)
 
 
 def execute_program(

@@ -26,6 +26,7 @@ from repro.comms.executor import (
     execute_program,
     gather_slots,
     plan_buffers_cached,
+    slot_rows,
 )
 from repro.core.engine import SynthesisEngine
 from repro.core.registry import default_registry, topology_fingerprint
@@ -229,11 +230,12 @@ def pccl_all_gather(
     for dev in devices:
         (chunk,) = by_src[dev]
         my_chunk_slot[dev] = plan.slot_of[(dev, chunk)]
+    rows = slot_rows(x.shape, x.dtype)
     with jax.named_scope("pccl.place"):
         idx = lax.axis_index(axis_name)
-        buf = jnp.zeros((plan.buffer_slots, *x.shape), x.dtype)
+        buf = jnp.zeros((plan.buffer_slots, *rows), x.dtype)
         buf = lax.dynamic_update_index_in_dim(
-            buf, x, jnp.asarray(my_chunk_slot)[idx], axis=0
+            buf, jnp.reshape(x, rows), jnp.asarray(my_chunk_slot)[idx], axis=0
         )
     buf = execute_program(plan, buf, axis_name)
     ordered_chunks = [by_src[d][0] for d in devices]
@@ -242,6 +244,7 @@ def pccl_all_gather(
         # non-participants may have forwarded chunks sitting in their slots
         # — mask so their output is untouched-by-the-collective zeros
         out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        out = jnp.reshape(out, (len(devices), *x.shape))
         return jnp.concatenate(list(out), axis=0) if tiled else out
 
 
@@ -271,12 +274,14 @@ def pccl_reduce_scatter(
             got = plan.slot_of.get((dev, c))
             if got is not None:
                 init_slot[dev, ci] = got
+    rows = slot_rows(x.shape[1:], x.dtype)
     with jax.named_scope("pccl.place"):
+        xs = jnp.reshape(x, (x.shape[0], *rows))
         idx = lax.axis_index(axis_name)
-        buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
+        buf = jnp.zeros((plan.buffer_slots, *rows), x.dtype)
         for ci in range(len(chunks)):
             buf = lax.dynamic_update_index_in_dim(
-                buf, x[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
+                buf, xs[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
             )
     buf = execute_program(plan, buf, axis_name)
     # each group device extracts its own chunk
@@ -290,7 +295,8 @@ def pccl_reduce_scatter(
         out = lax.dynamic_index_in_dim(
             buf, jnp.asarray(out_slot)[idx], axis=0, keepdims=False
         )
-        return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        return jnp.reshape(out, x.shape[1:])
 
 
 def pccl_all_reduce(
@@ -318,10 +324,11 @@ def pccl_all_reduce(
             got = plan.slot_of.get((dev, c))
             if got is not None:
                 init_slot[dev, ci] = got
+    rows = slot_rows((x.shape[0] // g, *x.shape[1:]), x.dtype)
     with jax.named_scope("pccl.place"):
-        xs = jnp.reshape(x, (g, x.shape[0] // g, *x.shape[1:]))
+        xs = jnp.reshape(x, (g, *rows))
         idx = lax.axis_index(axis_name)
-        buf = jnp.zeros((plan.buffer_slots, *xs.shape[1:]), x.dtype)
+        buf = jnp.zeros((plan.buffer_slots, *rows), x.dtype)
         for ci in range(g):
             buf = lax.dynamic_update_index_in_dim(
                 buf, xs[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
@@ -361,28 +368,31 @@ def pccl_all_to_all(
         recv_chunk_slot[dst, i] = plan.slot_of[(dst, chunk)]
     for dev in devices:
         self_row[dev] = rank_of_device[dev]
+    rows = slot_rows(x.shape[1:], x.dtype)
     with jax.named_scope("pccl.place"):
+        xs = jnp.reshape(x, (g, *rows))
         idx = lax.axis_index(axis_name)
-        buf = jnp.zeros((plan.buffer_slots, *x.shape[1:]), x.dtype)
+        buf = jnp.zeros((plan.buffer_slots, *rows), x.dtype)
         for j in range(g):
             buf = lax.dynamic_update_index_in_dim(
-                buf, x[j], jnp.asarray(send_chunk_slot[:, j])[idx], axis=0
+                buf, xs[j], jnp.asarray(send_chunk_slot[:, j])[idx], axis=0
             )
     buf = execute_program(plan, buf, axis_name)
     with jax.named_scope("pccl.gather"):
-        rows = []
+        received = []
         for i in range(g):
-            rows.append(
+            received.append(
                 lax.dynamic_index_in_dim(
                     buf, jnp.asarray(recv_chunk_slot[:, i])[idx], axis=0,
                     keepdims=False
                 )
             )
-        out = jnp.stack(rows)
+        out = jnp.stack(received)
         # self row: take from input (never transferred)
         me = jnp.asarray(self_row)[idx]
-        self_payload = lax.dynamic_index_in_dim(x, me, axis=0, keepdims=False)
+        self_payload = lax.dynamic_index_in_dim(xs, me, axis=0, keepdims=False)
         out = lax.dynamic_update_index_in_dim(out, self_payload, me, axis=0)
         # the self-row write above lands row 0 <- x[0] on non-participants
         # (self_row defaults to 0); mask them back to zeros
-        return jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        out = jnp.where(jnp.asarray(member)[idx], out, jnp.zeros_like(out))
+        return jnp.reshape(out, x.shape)

@@ -1,0 +1,165 @@
+"""The slot buffer's layout: a 1-D chunk that fills whole TPU tiles is held
+as rows of 128 lanes, any other chunk as it is. The layout moves bytes only,
+so every kind must return bit for bit what the flat layout returns on the
+same data, and what a plain reference returns."""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from repro import tracing
+from repro.comms.executor import slot_rows
+
+KINDS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
+FULL, SUBSET = (0, 1, 2, 3), (0, 3)  # (0, 3) routes through a non-member
+
+
+@pytest.mark.parametrize("shape, dtype, rows", [
+    ((1638400,), jnp.float32, (12800, 128)),
+    ((1024,), jnp.float32, (8, 128)),
+    ((1536,), jnp.float32, None),  # whole lanes, not whole sublanes
+    ((1000,), jnp.float32, None),
+    ((2048,), jnp.bfloat16, (16, 128)),
+    ((1024,), jnp.bfloat16, None),
+    ((4096,), jnp.int8, (32, 128)),
+    ((2048,), jnp.int8, None),
+    ((8, 128), jnp.float32, None),  # not 1-D
+    ((0,), jnp.float32, None),
+])
+def test_slot_rows_shape_and_counter(shape, dtype, rows):
+    before = tracing.counters()
+    got = slot_rows(shape, dtype)
+    after = tracing.counters()
+    assert got == (rows or shape)
+    engaged = "slot_layout.rows" if rows else "slot_layout.flat"
+    other = "slot_layout.flat" if rows else "slot_layout.rows"
+    assert after.get(engaged, 0) == before.get(engaged, 0) + 1
+    assert after.get(other, 0) == before.get(other, 0)
+
+
+# (kind, group, dtype, chunk length, layout the chunk takes)
+CASES = [
+    *((k, FULL, "float32", 2048, "rows") for k in KINDS),
+    *((k, FULL, "float32", 1000, "flat") for k in KINDS),
+    *((k, FULL, "bfloat16", 2048, "rows") for k in KINDS),
+    ("all_reduce", FULL, "bfloat16", 1024, "flat"),
+    *((k, SUBSET, "float32", 2048, "rows") for k in KINDS),
+]
+
+
+def _case_id(case):
+    kind, group, dtype, chunk, _ = case
+    return f"{kind}-g{len(group)}-{dtype}-{chunk}"
+
+
+_SCRIPT = r"""
+import json, sys
+import numpy as np
+import jax.numpy as jnp
+from _exec_harness import make_input, run_on_mesh
+from repro import tracing
+from repro.comms import primitives
+from repro.core import CollectiveRequest
+from repro.topology import mesh2d
+
+topo = mesh2d(2, 2)
+cases, out = json.loads(sys.argv[1]), sys.argv[2]
+rows_layout = primitives.slot_rows
+saved, counts = {}, []
+for i, (kind, group, dtype, chunk, _) in enumerate(cases):
+    x = make_input(kind, group, 4, payload=chunk, seed=i,
+                   dtype=jnp.dtype(dtype))
+    spec = CollectiveRequest(kind, group=tuple(group))
+    before = tracing.counters()
+    got = run_on_mesh(kind, topo, spec, x, n=4)
+    after = tracing.counters()
+    counts.append({k: after.get(k, 0) - before.get(k, 0)
+                   for k in ("slot_layout.rows", "slot_layout.flat")})
+    primitives.slot_rows = lambda shape, dtype: tuple(shape)
+    flat = run_on_mesh(kind, topo, spec, x, n=4)
+    primitives.slot_rows = rows_layout
+    for name, a in (("x", x), ("got", got), ("flat", flat)):
+        saved[f"{i}_{name}"] = a.view(f"u{a.dtype.itemsize}")
+np.savez(out + "/arrays.npz", **saved)
+json.dump(counts, open(out + "/counts.json", "w"))
+"""
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    """Every case run twice on 4 host CPU devices, with the layout the
+    chunk takes and with the flat layout forced (a child process: the
+    device count is fixed when JAX starts)."""
+    root = Path(__file__).resolve().parents[1]
+    out = tmp_path_factory.mktemp("slot_layout")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(root / "src"),
+                                           str(root / "tests")]))
+    p = subprocess.run([sys.executable, "-c", _SCRIPT, json.dumps(CASES),
+                        str(out)], capture_output=True, text=True,
+                       timeout=600, env=env)
+    assert p.returncode == 0, p.stderr[-4000:]
+    with np.load(out / "arrays.npz") as npz:
+        arrays = dict(npz)
+    counts = json.loads((out / "counts.json").read_text())
+
+    def case(c):
+        i = CASES.index(c)
+        dtype = jnp.dtype(c[2])
+        x, got, flat = (arrays[f"{i}_{n}"].view(dtype)
+                        for n in ("x", "got", "flat"))
+        return x, got, flat, counts[i]
+
+    return case
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_layout_is_bit_identical_to_flat(case, runs):
+    _, got, flat, _ = runs(case)
+    np.testing.assert_array_equal(got.view(f"u{got.dtype.itemsize}"),
+                                  flat.view(f"u{flat.dtype.itemsize}"))
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_layout_matches_plain_reference(case, runs):
+    """Data movement exactly; a sum of g terms within (g - 1) units of
+    round-off of the sum of magnitudes, in any order."""
+    kind, group, _, _, _ = case
+    x, got, _, _ = runs(case)
+    gl, g = list(group), len(group)
+    xf = x.astype(np.float64)
+    eps = float(jnp.finfo(x.dtype).eps)
+    for i, d in enumerate(gl):
+        out = got[d].astype(np.float64)
+        if kind == "all_gather":
+            np.testing.assert_array_equal(out, xf[gl], err_msg=f"device {d}")
+        elif kind == "all_to_all":
+            np.testing.assert_array_equal(out, xf[gl, i], err_msg=f"device {d}")
+        else:
+            terms = xf[gl, i] if kind == "reduce_scatter" else xf[gl]
+            bound = (g - 1) * eps * np.abs(terms).sum(0)
+            assert (np.abs(out - terms.sum(0)) <= bound).all(), f"device {d}"
+
+
+@pytest.mark.parametrize("case", [c for c in CASES if c[1] == SUBSET],
+                         ids=_case_id)
+def test_non_members_return_exact_zeros(case, runs):
+    _, got, _, _ = runs(case)
+    for d in sorted(set(FULL) - set(case[1])):
+        assert not got[d].view(f"u{got.dtype.itemsize}").any(), f"device {d}"
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_counter_says_which_layout(case, runs):
+    *_, counts = runs(case)
+    layout = case[4]
+    assert counts == {"slot_layout.rows": int(layout == "rows"),
+                      "slot_layout.flat": int(layout == "flat")}

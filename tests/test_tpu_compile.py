@@ -12,6 +12,7 @@ test file. Keep these tests in this one file so one worker holds it.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -86,6 +87,35 @@ def test_pccl_collective_compiles_to_permutes(kind, mesh):
     assert "collective-permute" in hlo
     for builtin in ("all-gather", "all-reduce", "reduce-scatter", "all-to-all"):
         assert f" {builtin}(" not in hlo and f" {builtin}-start(" not in hlo
+
+
+def test_all_reduce_slot_buffer_is_whole_tiles(mesh):
+    """One 25 MiB f32 bucket, the DDP cell's: the slot buffer holds each
+    chunk as rows of 128 lanes, no array stripes the chunk across sublanes,
+    and placement needs no relayout loop."""
+    bucket = 6553600
+    planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
+                                    registry=AlgorithmRegistry())
+    program = planner.program("all_reduce", "x", 0, nbytes=bucket / 4 / 2**18)
+    spec = CollectiveRequest("all_reduce", group=(0, 1, 2, 3))
+    chunk = bucket // 4
+
+    def run(xl):
+        return primitives.pccl_all_reduce(xl, "x", None, spec,
+                                          program=program)
+
+    # each chip's bucket is 1-D, laid out in 1024-element runs, as DDP's is
+    x = jax.ShapeDtypeStruct((4 * bucket,), jnp.float32,
+                             sharding=NamedSharding(mesh, P("x")))
+    hlo = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("x"),
+                                out_specs=P("x"))).lower(x).compile().as_text()
+    slots = program[1].buffer_slots
+    assert f"f32[{slots},{chunk // 128},128]" in hlo
+    # a 1-D f32[chunk] (the permute's operand) is whole 1024-element runs
+    for dims in re.findall(r"f32\[(\d+(?:,\d+)+)\]", hlo):
+        *_, second, minor = (int(d) for d in dims.split(","))
+        assert not (minor == chunk and second < 8), f"f32[{dims}]"
+    assert " while(" not in hlo
 
 
 def test_flash_attention_compiles_natively(one_chip):
