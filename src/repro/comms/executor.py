@@ -242,7 +242,10 @@ def execute_program(
 ) -> jax.Array:
     """Run inside shard_map. `buf`: [plan.buffer_slots, *chunk_shape] local
     buffer with source chunks pre-placed at their planned slots. Returns the
-    final buffer; callers extract destination slots via `plan.slot_of`."""
+    final buffer; callers extract destination slots via `plan.slot_of`.
+    Counts ``executor.rounds`` by the plan's rounds once per call; the
+    primitives call it once per traced collective."""
+    count("executor.rounds", len(plan.rounds))
     idx = lax.axis_index(axis_name)
     send_t, recv_t, reduce_t = plan.round_tables()
     for r, rt in enumerate(plan.rounds):

@@ -51,11 +51,18 @@ CASES = [
     *((k, FULL, "bfloat16", 2048, "rows") for k in KINDS),
     ("all_reduce", FULL, "bfloat16", 1024, "flat"),
     *((k, SUBSET, "float32", 2048, "rows") for k in KINDS),
+    # tensor-parallel decode's payloads, given as each chip's 2-D input: a
+    # [32, 6144] bf16 all-reduce and a [32, 23168] bf16 logits all-gather
+    *((k, group, "bfloat16", shape, "flat") for group in (FULL, SUBSET)
+      for k, shape in (("all_reduce", [32, 6144]),
+                       ("all_gather", [32, 23168]))),
 ]
 
 
 def _case_id(case):
     kind, group, dtype, chunk, _ = case
+    if isinstance(chunk, list):
+        chunk = "x".join(map(str, chunk))
     return f"{kind}-g{len(group)}-{dtype}-{chunk}"
 
 
@@ -72,23 +79,31 @@ from repro.topology import mesh2d
 topo = mesh2d(2, 2)
 cases, out = json.loads(sys.argv[1]), sys.argv[2]
 rows_layout = primitives.slot_rows
-saved, counts = {}, []
+saved, counts, rounds = {}, [], []
 for i, (kind, group, dtype, chunk, _) in enumerate(cases):
-    x = make_input(kind, group, 4, payload=chunk, seed=i,
-                   dtype=jnp.dtype(dtype))
+    if isinstance(chunk, list):  # each chip's whole input
+        x = np.random.default_rng(i).standard_normal((4, *chunk)).astype(
+            jnp.dtype(dtype))
+    else:
+        x = make_input(kind, group, 4, payload=chunk, seed=i,
+                       dtype=jnp.dtype(dtype))
     spec = CollectiveRequest(kind, group=tuple(group))
     before = tracing.counters()
     got = run_on_mesh(kind, topo, spec, x, n=4)
     after = tracing.counters()
     counts.append({k: after.get(k, 0) - before.get(k, 0)
                    for k in ("slot_layout.rows", "slot_layout.flat")})
+    rounds.append([after.get("executor.rounds", 0)
+                   - before.get("executor.rounds", 0),
+                   primitives.synthesize_program(topo, spec)[0].num_rounds])
     primitives.slot_rows = lambda shape, dtype: tuple(shape)
     flat = run_on_mesh(kind, topo, spec, x, n=4)
     primitives.slot_rows = rows_layout
     for name, a in (("x", x), ("got", got), ("flat", flat)):
         saved[f"{i}_{name}"] = a.view(f"u{a.dtype.itemsize}")
 np.savez(out + "/arrays.npz", **saved)
-json.dump(counts, open(out + "/counts.json", "w"))
+json.dump({"counts": counts, "rounds": rounds},
+          open(out + "/counts.json", "w"))
 """
 
 
@@ -109,21 +124,21 @@ def runs(tmp_path_factory):
     assert p.returncode == 0, p.stderr[-4000:]
     with np.load(out / "arrays.npz") as npz:
         arrays = dict(npz)
-    counts = json.loads((out / "counts.json").read_text())
+    counted = json.loads((out / "counts.json").read_text())
 
     def case(c):
         i = CASES.index(c)
         dtype = jnp.dtype(c[2])
         x, got, flat = (arrays[f"{i}_{n}"].view(dtype)
                         for n in ("x", "got", "flat"))
-        return x, got, flat, counts[i]
+        return x, got, flat, counted["counts"][i], counted["rounds"][i]
 
     return case
 
 
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_layout_is_bit_identical_to_flat(case, runs):
-    _, got, flat, _ = runs(case)
+    _, got, flat, *_ = runs(case)
     np.testing.assert_array_equal(got.view(f"u{got.dtype.itemsize}"),
                                   flat.view(f"u{flat.dtype.itemsize}"))
 
@@ -133,7 +148,7 @@ def test_layout_matches_plain_reference(case, runs):
     """Data movement exactly; a sum of g terms within (g - 1) units of
     round-off of the sum of magnitudes, in any order."""
     kind, group, _, _, _ = case
-    x, got, _, _ = runs(case)
+    x, got, *_ = runs(case)
     gl, g = list(group), len(group)
     xf = x.astype(np.float64)
     eps = float(jnp.finfo(x.dtype).eps)
@@ -152,14 +167,21 @@ def test_layout_matches_plain_reference(case, runs):
 @pytest.mark.parametrize("case", [c for c in CASES if c[1] == SUBSET],
                          ids=_case_id)
 def test_non_members_return_exact_zeros(case, runs):
-    _, got, _, _ = runs(case)
+    _, got, *_ = runs(case)
     for d in sorted(set(FULL) - set(case[1])):
         assert not got[d].view(f"u{got.dtype.itemsize}").any(), f"device {d}"
 
 
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_counter_says_which_layout(case, runs):
-    *_, counts = runs(case)
+    *_, counts, _ = runs(case)
     layout = case[4]
     assert counts == {"slot_layout.rows": int(layout == "rows"),
                       "slot_layout.flat": int(layout == "flat")}
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_executor_rounds_counts_the_programs_rounds(case, runs):
+    """Tracing one collective adds its program's ``num_rounds``."""
+    *_, (counted, planned) = runs(case)
+    assert counted == planned > 0

@@ -118,6 +118,39 @@ def test_all_reduce_slot_buffer_is_whole_tiles(mesh):
     assert " while(" not in hlo
 
 
+@pytest.mark.parametrize("kind, shape", [("all_reduce", (32, 6144)),
+                                         ("all_gather", (32, 23168))])
+def test_tp_decode_slot_buffer_is_whole_tiles(kind, shape, mesh):
+    """Tensor-parallel decode's bf16 payloads, each chip's [32, 6144]
+    activations and [32, 23168] logits shard: every bf16 array's rows
+    fill its tiles (an all-reduce chunk is [8, 6144]), the slot buffer is
+    [slots, *chunk], and placement needs no relayout loop."""
+    planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
+                                    registry=AlgorithmRegistry())
+    nbytes = shape[0] * shape[1] * 2
+    program = planner.program(kind, "x", 0, nbytes=nbytes / 2**20 / (
+        4 if kind == "all_reduce" else 1))
+    spec = CollectiveRequest(kind, group=(0, 1, 2, 3))
+    fn = getattr(primitives, f"pccl_{kind}")
+    chunk = (shape[0] // 4, shape[1]) if kind == "all_reduce" else shape
+
+    def run(xl):
+        return fn(xl[0], "x", None, spec, program=program)[None]
+
+    x = jax.ShapeDtypeStruct((4, *shape), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("x")))
+    hlo = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("x"),
+                                out_specs=P("x"))).lower(x).compile().as_text()
+    slots = program[1].buffer_slots
+    assert f"bf16[{slots},{chunk[0]},{chunk[1]}]" in hlo
+    tiled = re.findall(r"bf16\[(\d+(?:,\d+)+)\]\{[\d,]+:T\((\d+),128\)", hlo)
+    assert tiled
+    for dims, rows in tiled:
+        *_, second, minor = (int(d) for d in dims.split(","))
+        assert second % int(rows) == 0 and minor % 128 == 0, f"bf16[{dims}]"
+    assert " while(" not in hlo
+
+
 def test_flash_attention_compiles_natively(one_chip):
     # llama3.2-1b widths: 32 query heads, 8 kv heads, head_dim 64
     q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16, sharding=one_chip)
