@@ -1,11 +1,13 @@
 """Execute PCCL-synthesized schedules as shard_map ppermute programs.
 
 This is the TPU adaptation of the paper's §4.8 (MSCCL translation): each
-synthesis wave becomes one `jax.lax.ppermute` over the device mesh. The
-synthesizer emits congestion-free neighbor-link transfers, so the permutes
-are ICI-neighbor permutes when mesh device i is the chip at the fabric
-position of NPU i (``chip_smoke.py --chips 4`` places a 2x2 v5e host by
-chip coordinates and checks every permute pair is one hop).
+synthesis wave becomes `jax.lax.ppermute`s over the device mesh, one per
+round of the lowered program, issued together while no round reads what
+an earlier one of them writes. The synthesizer emits congestion-free
+neighbor-link transfers, so the permutes are ICI-neighbor permutes when
+mesh device i is the chip at the fabric position of NPU i
+(``chip_smoke.py --chips 4`` places a 2x2 v5e host by chip coordinates
+and checks every permute pair is one hop).
 
 Buffers are functional: every device holds a [num_slots, *chunk_shape]
 array, a 1-D chunk that fills whole TPU tiles as rows of 128 (`slot_rows`).
@@ -50,6 +52,9 @@ class BufferPlan:
     # lazily-built stacked [num_rounds, num_devices] device arrays, shared by
     # every trace of this plan (see round_tables)
     _tables: tuple | None = field(default=None, repr=False, compare=False)
+    # lazily-built waves of round indices (see waves)
+    _waves: list[list[int]] | None = field(default=None, repr=False,
+                                           compare=False)
 
     @property
     def buffer_slots(self) -> int:
@@ -74,6 +79,39 @@ class BufferPlan:
             self._tables = (send, recv, red)
         send, recv, red = self._tables
         return jnp.asarray(send), jnp.asarray(recv), jnp.asarray(red)
+
+    def waves(self) -> list[list[int]]:
+        """The rounds split into waves, built once per plan at first
+        trace (not in ``plan_buffers``, so planning pays nothing): see
+        :func:`round_waves`."""
+        if self._waves is None:
+            self._waves = round_waves(self)
+        return self._waves
+
+
+def round_waves(plan: BufferPlan) -> list[list[int]]:
+    """Split a plan's rounds into maximal runs of consecutive rounds, *waves*, in
+    which no round sends a slot that an earlier round of its wave writes.
+
+    Round ``b`` joins the current wave unless some device sends in ``b``
+    (is a source of its ``perm``) from the slot that it receives into in
+    an earlier round of the wave. A non-sender's ``send_slot`` of 0 is no
+    read, and a non-receiver's write to the trash slot is no write. So
+    every send of a wave can read the buffer as the wave found it, and the
+    wave's permutes carry no data dependence on each other; a plan whose
+    rounds each read the last one's receive gets waves of one round."""
+    waves: list[list[int]] = []
+    # (device, slot) pairs that the current wave's receives write
+    written = np.zeros((plan.num_devices, plan.buffer_slots), dtype=bool)
+    for b, rt in enumerate(plan.rounds):
+        src = np.fromiter((s for s, _ in rt.perm), np.int64, len(rt.perm))
+        if waves and not written[src, rt.send_slot[src]].any():
+            waves[-1].append(b)
+        else:
+            waves.append([b])
+            written[:] = False
+        written[rt.is_recv, rt.recv_slot[rt.is_recv]] = True
+    return waves
 
 
 def plan_buffers(prog: PpermuteProgram) -> BufferPlan:
@@ -243,28 +281,47 @@ def execute_program(
     """Run inside shard_map. `buf`: [plan.buffer_slots, *chunk_shape] local
     buffer with source chunks pre-placed at their planned slots. Returns the
     final buffer; callers extract destination slots via `plan.slot_of`.
-    Counts ``executor.rounds`` by the plan's rounds once per call; the
-    primitives call it once per traced collective."""
+
+    The lowering split each synthesis wave into rounds that a ``ppermute``
+    can carry (one send and one receive a device); the executor re-joins
+    them. It runs the plan's waves (``BufferPlan.waves``): every send of a
+    wave reads the buffer as the wave found it, the wave's permutes go out
+    with no data dependence between them, so XLA keeps them in flight
+    together on their links, and then the receives apply in round order.
+    No round of a wave sends a slot that an earlier one writes, so the
+    result is bit for bit that of the rounds run one after another.
+    Counts ``executor.rounds`` and ``executor.waves`` by the plan's rounds
+    and waves once per call; the primitives call it once per traced
+    collective."""
+    waves = plan.waves()
     count("executor.rounds", len(plan.rounds))
+    count("executor.waves", len(waves))
     idx = lax.axis_index(axis_name)
     send_t, recv_t, reduce_t = plan.round_tables()
-    for r, rt in enumerate(plan.rounds):
+    for wave in waves:
         # named scopes tag each stage's ops for the profiler; they change
         # the ops' metadata only, not the compiled program
-        with jax.named_scope("pccl.send"):  # the round's table reads too
-            send_slot = send_t[r, idx]
-            recv_slot = recv_t[r, idx]
-            reduce_here = reduce_t[r, idx]
-            val = lax.dynamic_index_in_dim(buf, send_slot, axis=0,
-                                           keepdims=False)
+        with jax.named_scope("pccl.send"):  # the send table's reads too
+            vals = [lax.dynamic_index_in_dim(buf, send_t[r, idx], axis=0,
+                                             keepdims=False) for r in wave]
+            if len(vals) > 1:
+                # without the barrier XLA fuses a later round's send slice
+                # into the update of an earlier receive, which chains the
+                # wave's permutes again
+                vals = lax.optimization_barrier(vals)
         with jax.named_scope("pccl.permute"):
-            got = lax.ppermute(val, axis_name, rt.perm)
-        with jax.named_scope("pccl.receive"):
-            old = lax.dynamic_index_in_dim(buf, recv_slot, axis=0,
-                                           keepdims=False)
-            new = jnp.where(reduce_here, old + got, got)
-        with jax.named_scope("pccl.update"):
-            buf = lax.dynamic_update_index_in_dim(buf, new, recv_slot, axis=0)
+            gots = [lax.ppermute(val, axis_name, plan.rounds[r].perm)
+                    for r, val in zip(wave, vals)]
+        for r, got in zip(wave, gots):
+            with jax.named_scope("pccl.receive"):
+                recv_slot = recv_t[r, idx]
+                reduce_here = reduce_t[r, idx]
+                old = lax.dynamic_index_in_dim(buf, recv_slot, axis=0,
+                                               keepdims=False)
+                new = jnp.where(reduce_here, old + got, got)
+            with jax.named_scope("pccl.update"):
+                buf = lax.dynamic_update_index_in_dim(buf, new, recv_slot,
+                                                      axis=0)
     return buf
 
 

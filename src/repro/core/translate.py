@@ -160,7 +160,9 @@ def to_ppermute_program(
     round every device sends at most one chunk and receives at most one chunk
     (ppermute semantics). Store-and-forward causality is kept because waves
     execute in start-time order and a chunk's forward always starts at or
-    after its arrival wave.
+    after its arrival wave. The executor re-joins the rounds that this split
+    apart: it issues consecutive rounds together while none sends a slot
+    that an earlier one of them writes (``repro.comms.executor.round_waves``).
 
     Composed :class:`~repro.core.engine.PhasePlan` schedules (hierarchical
     sequential, chunk-pipelined, TE-routed, time-reversed, repaired) lower

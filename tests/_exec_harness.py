@@ -124,3 +124,63 @@ def check_collective(kind: str, topo, spec, group, *, n: int = 8,
             np.testing.assert_array_equal(
                 got[d], np.zeros_like(got[d]),
                 err_msg=f"{kind}: non-participant device {d} buffer touched")
+
+
+def run_rounds(plan, buf: np.ndarray, waves=None) -> np.ndarray:
+    """Numpy interpreter of a ``BufferPlan`` on every device's buffer at once,
+    ``buf``: ``[num_devices, buffer_slots, ...]``. Without ``waves`` the
+    rounds run one after another, as the plan states them. With ``waves``
+    (lists of round indices), every send of a wave reads the buffer as the
+    wave found it, and then the wave's receives apply in round order. A
+    receive writes ``old + got`` where it reduces, else ``got``; a device
+    that receives nothing gets zeros, into its trash slot."""
+    buf = buf.copy()
+    dev = np.arange(plan.num_devices)
+    tail = (1,) * (buf.ndim - 2)
+    for wave in waves if waves is not None else [[r] for r in
+                                                 range(len(plan.rounds))]:
+        sent = [buf[dev, plan.rounds[r].send_slot] for r in wave]
+        for r, vals in zip(wave, sent):
+            rt = plan.rounds[r]
+            got = np.zeros_like(vals)
+            for s, d in rt.perm:
+                got[d] = vals[s]
+            old = buf[dev, rt.recv_slot]
+            reduce_here = rt.is_reduce.reshape(-1, *tail)
+            buf[dev, rt.recv_slot] = np.where(reduce_here, old + got, got)
+    return buf
+
+
+def emulate_collective(kind: str, prog, plan, group, x: np.ndarray) -> np.ndarray:
+    """What ``pccl_all_reduce`` or ``pccl_all_gather`` returns on every
+    device, emulated in numpy in ``x``'s dtype with the rounds run one after
+    another: each chunk placed at its planned slot as the primitive places
+    it, ``run_rounds``, then the chunks read back (the trash slot where a
+    device holds none) and non-members masked to zeros."""
+    n, trash = prog.num_devices, plan.num_slots
+    members = list(group)
+    buf = np.zeros((n, plan.buffer_slots, x[0].size // (
+        len(members) if kind == "all_reduce" else 1)), x.dtype)
+    if kind == "all_reduce":
+        chunks = sorted(prog.chunk_holders)
+        parts = x.reshape(n, len(members), -1)
+        for d in range(n):
+            for ci, c in enumerate(chunks):
+                slot = plan.slot_of.get((d, c), trash) if d in members else trash
+                buf[d, slot] = parts[d, ci]
+    elif kind == "all_gather":
+        srcs = prog.chunk_srcs
+        chunks = [next(c for c in sorted(srcs) if srcs[c] == m) for m in members]
+        for d in range(n):
+            slot = (plan.slot_of[(d, chunks[members.index(d)])]
+                    if d in members else 0)
+            buf[d, slot] = x[d].reshape(-1)
+    else:
+        raise ValueError(kind)
+    buf = run_rounds(plan, buf)
+    out = np.stack([[buf[d, plan.slot_of.get((d, c), trash)] for c in chunks]
+                    for d in range(n)])
+    out[[d for d in range(n) if d not in members]] = 0
+    if kind == "all_reduce":
+        return out.reshape(x.shape)
+    return out.reshape(n, len(members), *x.shape[1:])

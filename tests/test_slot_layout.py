@@ -14,8 +14,13 @@ import pytest
 
 import jax.numpy as jnp
 
+from _exec_harness import emulate_collective
+
 from repro import tracing
+from repro.comms import primitives
 from repro.comms.executor import slot_rows
+from repro.core import CollectiveRequest
+from repro.topology import mesh2d
 
 KINDS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
 FULL, SUBSET = (0, 1, 2, 3), (0, 3)  # (0, 3) routes through a non-member
@@ -79,7 +84,7 @@ from repro.topology import mesh2d
 topo = mesh2d(2, 2)
 cases, out = json.loads(sys.argv[1]), sys.argv[2]
 rows_layout = primitives.slot_rows
-saved, counts, rounds = {}, [], []
+saved, counts, executor = {}, [], []
 for i, (kind, group, dtype, chunk, _) in enumerate(cases):
     if isinstance(chunk, list):  # each chip's whole input
         x = np.random.default_rng(i).standard_normal((4, *chunk)).astype(
@@ -93,16 +98,20 @@ for i, (kind, group, dtype, chunk, _) in enumerate(cases):
     after = tracing.counters()
     counts.append({k: after.get(k, 0) - before.get(k, 0)
                    for k in ("slot_layout.rows", "slot_layout.flat")})
-    rounds.append([after.get("executor.rounds", 0)
-                   - before.get("executor.rounds", 0),
-                   primitives.synthesize_program(topo, spec)[0].num_rounds])
+    prog, plan = primitives.synthesize_program(topo, spec)
+    executor.append({"rounds": [after.get("executor.rounds", 0)
+                                - before.get("executor.rounds", 0),
+                                prog.num_rounds],
+                     "waves": [after.get("executor.waves", 0)
+                               - before.get("executor.waves", 0),
+                               len(plan.waves())]})
     primitives.slot_rows = lambda shape, dtype: tuple(shape)
     flat = run_on_mesh(kind, topo, spec, x, n=4)
     primitives.slot_rows = rows_layout
     for name, a in (("x", x), ("got", got), ("flat", flat)):
         saved[f"{i}_{name}"] = a.view(f"u{a.dtype.itemsize}")
 np.savez(out + "/arrays.npz", **saved)
-json.dump({"counts": counts, "rounds": rounds},
+json.dump({"counts": counts, "executor": executor},
           open(out + "/counts.json", "w"))
 """
 
@@ -131,7 +140,7 @@ def runs(tmp_path_factory):
         dtype = jnp.dtype(c[2])
         x, got, flat = (arrays[f"{i}_{n}"].view(dtype)
                         for n in ("x", "got", "flat"))
-        return x, got, flat, counted["counts"][i], counted["rounds"][i]
+        return x, got, flat, counted["counts"][i], counted["executor"][i]
 
     return case
 
@@ -183,5 +192,38 @@ def test_counter_says_which_layout(case, runs):
 @pytest.mark.parametrize("case", CASES, ids=_case_id)
 def test_executor_rounds_counts_the_programs_rounds(case, runs):
     """Tracing one collective adds its program's ``num_rounds``."""
-    *_, (counted, planned) = runs(case)
+    *_, executor = runs(case)
+    counted, planned = executor["rounds"]
     assert counted == planned > 0
+
+
+# the waves each kind's plan on the 2x2 runs in: its rounds two at a time,
+# the full group's all-to-all's five as two and three
+WAVES = {"all_reduce": 4, "all_gather": 2, "reduce_scatter": 2,
+         "all_to_all": 2}
+
+
+@pytest.mark.parametrize("case", CASES, ids=_case_id)
+def test_executor_waves_counts_the_plans_waves(case, runs):
+    """Tracing one collective adds its plan's waves: 4 for the 2x2
+    all-reduce's 8 rounds, 2 for the all-gather's 4."""
+    *_, executor = runs(case)
+    counted, planned = executor["waves"]
+    assert counted == planned == WAVES[case[0]]
+
+
+@pytest.mark.parametrize(
+    "case", [c for c in CASES if c[0] in ("all_reduce", "all_gather")],
+    ids=_case_id)
+def test_waves_are_bit_equal_to_rounds_in_series(case, runs):
+    """The executor runs each wave's permutes together; what it returns is
+    bit for bit a numpy emulation that runs the plan's rounds one after
+    another, in the payload's dtype."""
+    kind, group, *_ = case
+    x, got, *_ = runs(case)
+    prog, plan = primitives.synthesize_program(
+        mesh2d(2, 2), CollectiveRequest(kind, group=tuple(group)))
+    want = emulate_collective(kind, prog, plan, group, x)
+    assert want.dtype == got.dtype
+    np.testing.assert_array_equal(got.view(f"u{got.dtype.itemsize}"),
+                                  want.view(f"u{want.dtype.itemsize}"))

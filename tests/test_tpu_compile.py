@@ -151,6 +151,43 @@ def test_tp_decode_slot_buffer_is_whole_tiles(kind, shape, mesh):
     assert " while(" not in hlo
 
 
+@pytest.mark.parametrize("shape, dtype", [((6553600,), jnp.float32),
+                                          ((32, 6144), jnp.bfloat16)],
+                         ids=["ddp-bucket-f32", "tp-decode-bf16"])
+def test_all_reduce_keeps_two_permutes_in_flight(shape, dtype, mesh):
+    """The DDP cell's 25 MiB f32 bucket and the TP decode cell's [32, 6144]
+    bf16 activations: the 2x2 all-reduce's 8 permutes run as 4 waves of
+    2, so at 4 points of the scheduled program a permute starts before the
+    one started last is done."""
+    planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
+                                    registry=AlgorithmRegistry())
+    itemsize = jnp.dtype(dtype).itemsize
+    program = planner.program("all_reduce", "x", 0, nbytes=int(
+        np.prod(shape)) * itemsize / 4 / 2**20)
+    spec = CollectiveRequest("all_reduce", group=(0, 1, 2, 3))
+
+    def run(xl):
+        return primitives.pccl_all_reduce(xl[0], "x", None, spec,
+                                          program=program)[None]
+
+    x = jax.ShapeDtypeStruct((4, *shape), dtype,
+                             sharding=NamedSharding(mesh, P("x")))
+    hlo = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("x"),
+                                out_specs=P("x"))).lower(x).compile().as_text()
+    entry = hlo[hlo.index("\nENTRY"):]  # a scheduled module lists in order
+    events = re.findall(r"%collective-permute-(start|done)(?:\.\d+)? = ",
+                        entry)
+    assert events.count("start") == events.count("done") == 8
+    in_flight = overlapped = 0
+    for event in events:
+        if event == "start":
+            overlapped += in_flight > 0
+            in_flight += 1
+        else:
+            in_flight -= 1
+    assert overlapped == 4
+
+
 def test_flash_attention_compiles_natively(one_chip):
     # llama3.2-1b widths: 32 query heads, 8 kv heads, head_dim 64
     q = jax.ShapeDtypeStruct((1, 4096, 32, 64), jnp.bfloat16, sharding=one_chip)
