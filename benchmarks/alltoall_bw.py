@@ -5,7 +5,7 @@ paper — optimizer-based synthesis is out of scope of this repo.)"""
 from __future__ import annotations
 
 from benchmarks.common import Row, timed
-from repro.core import direct_all_to_all, synthesize_all_to_all
+from repro.core import CollectiveRequest, SynthesisEngine, direct_all_to_all
 from repro.topology import mesh2d
 
 
@@ -16,7 +16,8 @@ def run(full: bool = False) -> list[Row]:
         topo = mesh2d(side, side)
         n = side * side
         group = list(range(n))
-        alg, us = timed(synthesize_all_to_all, topo, group)
+        alg, us = timed(SynthesisEngine(topo).collective,
+                        CollectiveRequest("all_to_all", group))
         alg.validate()
         direct = direct_all_to_all(topo, group)
         # normalized algorithmic bandwidth = payload / time, direct == 1.0

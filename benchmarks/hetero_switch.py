@@ -16,9 +16,9 @@ from __future__ import annotations
 from benchmarks.common import Row, timed
 from repro.core import (
     AlgorithmRegistry,
+    CollectiveRequest,
     SynthesisEngine,
     direct_all_to_all,
-    synthesize_all_to_all,
 )
 from repro.topology import multi_pod, two_level_switch
 
@@ -41,7 +41,8 @@ def _te_rows(full: bool) -> list[Row]:
             for strategy in ("round_robin", "te"):
                 engine = SynthesisEngine(topo, registry=AlgorithmRegistry(),
                                          gateway_strategy=strategy)
-                alg, t = timed(getattr(engine, kind), topo.npus, bytes=4.0)
+                alg, t = timed(engine.collective, CollectiveRequest(
+                    kind, topo.npus, bytes=4.0))
                 alg.validate(mode="bulk")
                 spans[strategy] = alg.makespan
                 if strategy == "te":
@@ -63,7 +64,8 @@ def run(full: bool = False) -> list[Row]:
         topo = two_level_switch(nodes, npus_per_node=8)
         n = nodes * 8
         group = list(range(n))
-        alg, us = timed(synthesize_all_to_all, topo, group, bytes=128.0)
+        alg, us = timed(SynthesisEngine(topo).collective,
+                        CollectiveRequest("all_to_all", group, bytes=128.0))
         alg.validate()
         direct = direct_all_to_all(topo, group, bytes=128.0)
         speedup = direct.makespan / alg.makespan if alg.makespan else 0.0

@@ -31,7 +31,7 @@ from repro.topology import multi_pod, three_level
 def _cold_row(name: str, topo, kind: str, mode: str = "auto") -> Row:
     reg = AlgorithmRegistry()
     eng = SynthesisEngine(topo, registry=reg)
-    alg, us = timed(getattr(eng, kind), topo.npus)
+    alg, us = timed(eng.collective, CollectiveRequest(kind, topo.npus))
     _, val_us = timed(alg.validate, mode)
     n = len(topo.npus)
     return Row(
@@ -79,7 +79,8 @@ def run(full: bool = False) -> list[Row]:
     topo = multi_pod(2, 4, 8, unit_links=True)
     eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
     for kind in ("all_gather", "all_to_all", "reduce_scatter", "all_reduce"):
-        hier, hier_us = timed(getattr(eng, kind), topo.npus)
+        hier, hier_us = timed(eng.collective,
+                               CollectiveRequest(kind, topo.npus))
         flat, flat_us = timed(eng.collective, CollectiveRequest(
             kind, group=tuple(topo.npus), hierarchy="never"))
         hier.validate()

@@ -6,7 +6,7 @@ advantage narrows."""
 from __future__ import annotations
 
 from benchmarks.common import Row, timed
-from repro.core import ChunkIds, all_to_all, synthesize_joint
+from repro.core import ChunkIds, SynthesisEngine, all_to_all
 from repro.topology import mesh2d
 
 from benchmarks.process_group import _direct_joint
@@ -25,7 +25,7 @@ def run(full: bool = False) -> list[Row]:
         ids = ChunkIds()
         named = [(f"pg{i}", all_to_all(grp, ids=ids))
                  for i, grp in enumerate(groups)]
-        alg, us = timed(synthesize_joint, topo, named)
+        alg, us = timed(SynthesisEngine(topo).synthesize_joint, named)
         alg.validate()
         direct = _direct_joint(topo, groups)
         speedup = direct.makespan / alg.makespan

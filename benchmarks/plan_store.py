@@ -30,6 +30,7 @@ import numpy as np
 from benchmarks.common import Row, timed
 from repro.core import (
     AlgorithmRegistry,
+    CollectiveRequest,
     SynthesisEngine,
     Transfer,
     load_plan_npz,
@@ -72,7 +73,8 @@ def _object_path_bytes(n: int) -> int:
 def _rows_for(topo, n: int) -> list[Row]:
     reg = AlgorithmRegistry()
     eng = SynthesisEngine(topo, registry=reg)
-    alg, synth_us = timed(eng.all_reduce, topo.npus)
+    alg, synth_us = timed(eng.collective,
+                          CollectiveRequest("all_reduce", topo.npus))
     _, val_us = timed(alg.validate, "bulk")
     cols = alg.columns
     nt = len(cols)

@@ -9,10 +9,10 @@ from benchmarks.common import Row, timed
 from repro.core import (
     ChunkIds,
     Flow,
+    SynthesisEngine,
     all_to_all,
     shortest_path_links,
     simulate_flows,
-    synthesize_joint,
 )
 from repro.topology import mesh2d
 
@@ -39,7 +39,7 @@ def run(full: bool = False) -> list[Row]:
         ids = ChunkIds()
         named = [(f"row{r}", all_to_all(g, ids=ids))
                  for r, g in enumerate(groups)]
-        alg, us = timed(synthesize_joint, topo, named)
+        alg, us = timed(SynthesisEngine(topo).synthesize_joint, named)
         alg.validate()
         direct = _direct_joint(topo, groups)
         speedup = direct.makespan / alg.makespan

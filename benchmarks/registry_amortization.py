@@ -7,7 +7,7 @@ served by automorphism relabeling from the AlgorithmRegistry."""
 from __future__ import annotations
 
 from benchmarks.common import Row, timed
-from repro.core import AlgorithmRegistry, SynthesisEngine
+from repro.core import AlgorithmRegistry, CollectiveRequest, SynthesisEngine
 from repro.topology.generators import torus2d
 
 
@@ -24,14 +24,14 @@ def run(full: bool = False) -> list[Row]:
             registry = AlgorithmRegistry()
             engine = SynthesisEngine(topo, registry=registry)
             rows = _rows(side)
-            synth = getattr(engine, kind)
 
-            cold_alg, cold_us = timed(synth, rows[0])
+            cold_alg, cold_us = timed(engine.collective,
+                                      CollectiveRequest(kind, rows[0]))
             cold_alg.validate()
 
             hit_us_total = 0.0
             for row in rows[1:]:
-                alg, us = timed(synth, row)
+                alg, us = timed(engine.collective, CollectiveRequest(kind, row))
                 hit_us_total += us
                 assert alg.makespan == cold_alg.makespan
             hit_us = hit_us_total / max(len(rows) - 1, 1)

@@ -4,7 +4,7 @@ a fixed mesh — scaling in the *collective* dimension rather than topology."""
 from __future__ import annotations
 
 from benchmarks.common import Row, timed
-from repro.core import synthesize_all_to_all
+from repro.core import CollectiveRequest, SynthesisEngine
 from repro.topology import mesh2d
 from repro.topology.generators import grid_hypercube
 
@@ -16,8 +16,8 @@ def run(full: bool = False) -> list[Row]:
     n = side * side
     chunk_counts = [1, 2, 4] + ([8, 16] if full else [])
     for chunks in chunk_counts:
-        alg, us = timed(synthesize_all_to_all, topo, list(range(n)),
-                        chunks_per_pair=chunks)
+        alg, us = timed(SynthesisEngine(topo).collective, CollectiveRequest(
+            "all_to_all", list(range(n)), chunks=chunks))
         alg.validate()
         rows.append(Row(
             f"fig12_chunks_mesh{side}x{side}_c{chunks}", us,
@@ -26,8 +26,8 @@ def run(full: bool = False) -> list[Row]:
     nn = len(cube.npus)
     for chunks in chunk_counts:
         # flat-path scaling row (hierarchical rows live in fig_hier_*)
-        alg, us = timed(synthesize_all_to_all, cube, list(range(nn)),
-                        chunks_per_pair=chunks, hierarchy="never")
+        alg, us = timed(SynthesisEngine(cube).collective, CollectiveRequest(
+            "all_to_all", list(range(nn)), chunks=chunks, hierarchy="never"))
         alg.validate()
         rows.append(Row(
             f"fig12_chunks_cube_{nn}_c{chunks}", us,

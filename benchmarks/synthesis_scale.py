@@ -5,7 +5,7 @@ Hypercube). PCCL's headline scalability claim: tractable growth (O(n^3)),
 from __future__ import annotations
 
 from benchmarks.common import Row, timed
-from repro.core import synthesize_all_to_all
+from repro.core import CollectiveRequest, SynthesisEngine
 from repro.topology import mesh2d
 from repro.topology.generators import grid_hypercube
 
@@ -16,7 +16,8 @@ def run(full: bool = False) -> list[Row]:
     for side in mesh_sides:
         topo = mesh2d(side, side)
         n = side * side
-        alg, us = timed(synthesize_all_to_all, topo, list(range(n)))
+        alg, us = timed(SynthesisEngine(topo).collective,
+                        CollectiveRequest("all_to_all", list(range(n))))
         alg.validate()
         rows.append(Row(
             f"fig11_synthesis_mesh{side}x{side}", us,
@@ -27,8 +28,8 @@ def run(full: bool = False) -> list[Row]:
         n = side ** 3
         # fig11 tracks *flat* synthesis scaling; grid_hypercube fabrics are
         # partitioned now, so pin the flat path (fig_hier_* covers hierarchy)
-        alg, us = timed(synthesize_all_to_all, topo, list(range(n)),
-                        hierarchy="never")
+        alg, us = timed(SynthesisEngine(topo).collective, CollectiveRequest(
+            "all_to_all", list(range(n)), hierarchy="never"))
         alg.validate()
         rows.append(Row(
             f"fig11_synthesis_cube{side}^3", us,

@@ -6,9 +6,10 @@ from __future__ import annotations
 
 from benchmarks.common import Row, timed
 from repro.core import (
+    CollectiveRequest,
+    SynthesisEngine,
     direct_all_to_all,
     replay_algorithm,
-    synthesize_all_to_all,
 )
 from repro.topology import mesh2d
 
@@ -20,7 +21,8 @@ def run(full: bool = False) -> list[Row]:
     n = side * side
     for pg_size in (n, n // 2):
         group = list(range(pg_size))
-        alg, us = timed(synthesize_all_to_all, topo, group)
+        alg, us = timed(SynthesisEngine(topo).collective,
+                        CollectiveRequest("all_to_all", group))
         alg.validate()
         direct = direct_all_to_all(topo, group)
         speedup = direct.makespan / alg.makespan

@@ -13,10 +13,10 @@ synthesis problem. A single collective goes through the
 
 from repro.core import (
     ChunkIds,
+    SynthesisEngine,
     all_gather,
     all_to_allv,
     replay_algorithm,
-    synthesize_joint,
 )
 from repro.topology import mesh2d, tpu_v5e_pod
 
@@ -30,7 +30,8 @@ def main():
     v_ids, ag_ids = ChunkIds().split(2)
     v = all_to_allv([0, 1, 2], [[0, 2, 2], [1, 0, 1], [1, 1, 0]], ids=v_ids)
     ag = all_gather([6, 7, 8], ids=ag_ids, chunks_per_npu=2)
-    alg = synthesize_joint(topo, [("a2av", v), ("allgather", ag)])
+    alg = SynthesisEngine(topo).synthesize_joint(
+        [("a2av", v), ("allgather", ag)])
     alg.validate()
     used = {t.src for t in alg.transfers} | {t.dst for t in alg.transfers}
     outside = sorted(used - {0, 1, 2, 6, 7, 8})
@@ -49,7 +50,7 @@ def main():
     for r, row_ids in enumerate(ChunkIds().split(8)):
         row = [r * 8 + c for c in range(8)]
         groups.append((f"ep_row{r}", all_to_all(row, ids=row_ids, bytes=1.0)))
-    alg = synthesize_joint(pod, groups)
+    alg = SynthesisEngine(pod).synthesize_joint(groups)
     alg.validate()
     print("\n8x8 pod, 8 concurrent EP All-to-All groups:")
     print(f"  makespan={alg.makespan:.1f} us, transfers={alg.num_transfers}")

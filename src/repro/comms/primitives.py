@@ -13,7 +13,6 @@ program embeds the static permute rounds.
 from __future__ import annotations
 
 from collections import OrderedDict
-from dataclasses import dataclass
 
 import numpy as np
 
@@ -36,44 +35,24 @@ from repro.topology.topology import Topology
 from repro.tracing import count, span
 
 
-@dataclass(frozen=True)
-class CollectiveSpec:
-    """What to synthesize: collective kind over a device group embedded in a
-    physical topology. `device_of_npu` maps topology NPU ids to mesh axis
-    indices; it must cover every NPU that may forward traffic (the whole
-    topology for process-group-aware routing).
-
-    Everywhere a ``CollectiveSpec`` is accepted, a fully-specified
-    :class:`~repro.core.request.CollectiveRequest` works too — that is the
-    way to execute hierarchy/TE/pipelining-routed plans, since the request
-    carries ``hierarchy``/``gateway_strategy``/``sketch``/``pipelined``."""
-
-    kind: str  # all_gather | reduce_scatter | all_reduce | all_to_all
-    group: tuple[int, ...]  # NPU ids of the process group, in axis order
-
+# ``chipbench/generators`` names the request type passed next to a
+# ready ``program=`` by this older name; only ``.kind`` and ``.group``
+# are read there
+CollectiveSpec = CollectiveRequest
 
 _EXEC_KINDS = ("all_gather", "all_to_all", "reduce_scatter", "all_reduce")
 
 
-def _as_request(spec, nbytes: float, pipelined_ar: bool) -> CollectiveRequest:
-    """Normalize CollectiveSpec | CollectiveRequest into a CollectiveRequest."""
-    if isinstance(spec, CollectiveRequest):
-        req = spec
-    elif isinstance(spec, CollectiveSpec):
-        req = CollectiveRequest(
-            spec.kind, group=tuple(spec.group), bytes=nbytes,
-            pipelined=pipelined_ar if spec.kind == "all_reduce" else False)
-    else:
+def _check_executable(req) -> None:
+    if not isinstance(req, CollectiveRequest):
         raise TypeError(
-            f"spec must be CollectiveSpec or CollectiveRequest, "
-            f"got {type(spec).__name__}")
+            f"request must be a CollectiveRequest, got {type(req).__name__}")
     if req.kind not in _EXEC_KINDS:
         raise ValueError(
             f"collective kind {req.kind!r} is not executable "
             f"(expected one of {_EXEC_KINDS})")
     if not req.group:
         raise ValueError("executable collectives need an explicit group")
-    return req
 
 
 # translated programs, keyed by fingerprint (bounded LRU; BufferPlans are
@@ -103,11 +82,9 @@ def _engine_for(topo: Topology, registry) -> SynthesisEngine:
 
 def synthesize_program(
     topo: Topology,
-    spec,
+    request: CollectiveRequest,
     *,
-    nbytes: float = 1.0,
     device_of_npu: dict[int, int] | None = None,
-    pipelined_ar: bool = True,
     registry=None,
 ) -> tuple[PpermuteProgram, BufferPlan]:
     """Synthesis -> translation -> buffer planning, cached at every layer:
@@ -116,17 +93,16 @@ def synthesize_program(
     and the BufferPlan through the executor's plan cache (the single owner
     of plans; every call goes through it, so its stats reflect real reuse).
 
-    ``spec`` is a :class:`CollectiveSpec` (legacy default route) or a
-    :class:`~repro.core.request.CollectiveRequest` — the latter executes any
-    engine route: ``hierarchy="always"``, TE gateway strategies, comm
-    sketches, pipelined all-reduce. ``nbytes``/``pipelined_ar`` only apply
-    to the CollectiveSpec form; a request carries its own."""
+    ``request`` is a :class:`~repro.core.request.CollectiveRequest` of an
+    executable kind over an explicit group; it executes any engine route:
+    ``hierarchy="always"``, TE gateway strategies, comm sketches, pipelined
+    all-reduce."""
     with span("pccl.plan"):
         registry = registry if registry is not None else default_registry()
-        req = _as_request(spec, nbytes, pipelined_ar)
+        _check_executable(request)
         dev_key = (None if device_of_npu is None
                    else tuple(sorted(device_of_npu.items())))
-        key = (topology_fingerprint(topo), req.fingerprint(), dev_key)
+        key = (topology_fingerprint(topo), request.fingerprint(), dev_key)
         prog = _PROGRAM_CACHE.get(key)
         if prog is not None:
             _PROGRAM_CACHE.move_to_end(key)
@@ -134,7 +110,7 @@ def synthesize_program(
         else:
             count("program_cache.miss")
             engine = _engine_for(topo, registry)
-            alg = engine.collective(req)
+            alg = engine.collective(request)
             with span("pccl.validate"):
                 alg.validate()
             with span("pccl.translate"):
@@ -316,8 +292,8 @@ def pccl_all_reduce(
     g = len(devices)
     chunks = sorted(prog.chunk_holders)
     assert len(chunks) == g, "all_reduce uses one shard-chunk per member"
-    # chunk order follows group order by construction (see
-    # synthesizer.synthesize_all_reduce: reduce_scatter iterates the group)
+    # chunk order follows group order by construction (the engine's
+    # all-reduce is a reduce-scatter that iterates the group)
     init_slot = np.full((prog.num_devices, g), plan.num_slots, np.int32)
     for ci, c in enumerate(chunks):
         for dev in devices:

@@ -20,12 +20,12 @@ from jax import lax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.comms.primitives import (  # noqa: E402
-    CollectiveSpec,
     pccl_all_gather,
     pccl_all_reduce,
     pccl_all_to_all,
     pccl_reduce_scatter,
 )
+from repro.core.request import CollectiveRequest  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.topology import line, ring, torus2d  # noqa: E402
 
@@ -43,7 +43,7 @@ def check(name, got, want, atol=1e-6):
 def test_all_gather_ring():
     mesh = _mesh1d()
     topo = ring(8, bidirectional=True)
-    spec = CollectiveSpec("all_gather", tuple(range(8)))
+    spec = CollectiveRequest("all_gather", tuple(range(8)))
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 
     @jax.jit
@@ -65,7 +65,7 @@ def test_all_gather_subgroup_with_forwarding():
     mesh = _mesh1d()
     topo = line(8)
     group = (0, 3, 7)
-    spec = CollectiveSpec("all_gather", group)
+    spec = CollectiveRequest("all_gather", group)
     x = jnp.arange(8 * 2, dtype=jnp.float32).reshape(8, 2)
 
     @jax.jit
@@ -85,7 +85,7 @@ def test_all_gather_subgroup_with_forwarding():
 def test_all_reduce():
     mesh = _mesh1d()
     topo = ring(8, bidirectional=True)
-    spec = CollectiveSpec("all_reduce", tuple(range(8)))
+    spec = CollectiveRequest("all_reduce", tuple(range(8)), pipelined=True)
     x = jnp.arange(8 * 8, dtype=jnp.float32).reshape(8, 8) * 0.25
 
     @jax.jit
@@ -105,7 +105,7 @@ def test_all_reduce():
 def test_reduce_scatter():
     mesh = _mesh1d()
     topo = ring(8, bidirectional=True)
-    spec = CollectiveSpec("reduce_scatter", tuple(range(8)))
+    spec = CollectiveRequest("reduce_scatter", tuple(range(8)))
     x = jnp.arange(8 * 8 * 3, dtype=jnp.float32).reshape(8, 8, 3)
 
     @jax.jit
@@ -126,7 +126,7 @@ def test_all_to_all_torus_rows():
     """A2A over the full 8-device group on a 2x4 torus."""
     mesh = _mesh1d()
     topo = torus2d(2, 4)
-    spec = CollectiveSpec("all_to_all", tuple(range(8)))
+    spec = CollectiveRequest("all_to_all", tuple(range(8)))
     x = jnp.arange(8 * 8 * 2, dtype=jnp.float32).reshape(8, 8, 2)
 
     @jax.jit
@@ -149,7 +149,7 @@ def test_all_to_all_subgroup():
     mesh = _mesh1d()
     topo = line(8)
     group = (0, 2, 5)
-    spec = CollectiveSpec("all_to_all", group)
+    spec = CollectiveRequest("all_to_all", group)
     x = jnp.arange(8 * 3 * 2, dtype=jnp.float32).reshape(8, 3, 2)
 
     @jax.jit
@@ -172,7 +172,7 @@ def test_two_axis_flattened():
     """Executor over a flattened ('r','c') mesh — the full-pod execution mode."""
     mesh = make_mesh((2, 4), ("r", "c"))
     topo = torus2d(2, 4)
-    spec = CollectiveSpec("all_gather", tuple(range(8)))
+    spec = CollectiveRequest("all_gather", tuple(range(8)))
     x = jnp.arange(8 * 2, dtype=jnp.float32).reshape(8, 2)
 
     @jax.jit

@@ -24,7 +24,12 @@ from repro.core.conditions import (
     reduce_scatter,
     scatter,
 )
-from repro.core.engine import PhasePlan, PhaseSpec, SynthesisEngine
+from repro.core.engine import (
+    PhasePlan,
+    PhaseSpec,
+    SynthesisEngine,
+    order_conditions,
+)
 from repro.core.errors import FabricDegradedError, PCCLError
 from repro.core.hierarchy import HierarchicalSynthesizer, HierarchyError
 from repro.core.repair import (
@@ -33,10 +38,7 @@ from repro.core.repair import (
     PlanRepairer,
     RepairResult,
 )
-from repro.core.request import (
-    CollectiveRequest,
-    PCCLDeprecationWarning,
-)
+from repro.core.request import CollectiveRequest
 from repro.core.traffic import CommSketch, SketchInfeasibleError, \
     TrafficEngineer
 from repro.core.registry import (
@@ -47,16 +49,6 @@ from repro.core.registry import (
     is_automorphism,
     relabel_algorithm,
     topology_fingerprint,
-)
-from repro.core.synthesizer import (
-    order_conditions,
-    synthesize,
-    synthesize_all_gather,
-    synthesize_all_reduce,
-    synthesize_all_to_all,
-    synthesize_joint,
-    synthesize_reduce,
-    synthesize_reduce_scatter,
 )
 from repro.core.simulator import (
     Flow,
@@ -103,7 +95,6 @@ __all__ = [
     "PCCLError",
     "FabricDegradedError",
     "CollectiveRequest",
-    "PCCLDeprecationWarning",
     "DamageReport",
     "DegradationEvent",
     "PlanRepairer",
@@ -133,13 +124,6 @@ __all__ = [
     "reduce_scatter",
     "scatter",
     "order_conditions",
-    "synthesize",
-    "synthesize_all_gather",
-    "synthesize_all_reduce",
-    "synthesize_all_to_all",
-    "synthesize_joint",
-    "synthesize_reduce",
-    "synthesize_reduce_scatter",
     "Flow",
     "SimResult",
     "collective_bandwidth",

@@ -1,10 +1,8 @@
 """SynthesisEngine: the single owner of the PCCL synthesis loop.
 
-Historically every ``synthesize*`` front-end in :mod:`repro.core.synthesizer`
-re-implemented the same lifecycle: build a TEN, pick int/cont mode, order
-conditions, run BFS per condition, commit the pruned paths. The engine owns
-that lifecycle in one place (paper §4.4, Algorithm 3) and adds two things the
-front-ends could not:
+The engine owns the synthesis lifecycle in one place (paper §4.4,
+Algorithm 3): build a TEN, pick int/cont mode, order conditions, run BFS per
+condition, commit the pruned paths. On top of it sit:
 
 * a per-topology distance cache shared across calls (condition ordering no
   longer recomputes shortest paths for every collective on the same fabric);
@@ -13,14 +11,15 @@ front-ends could not:
   registry so isomorphic process groups reuse one synthesized, canonicalized
   plan instead of redoing the TEN/BFS work.
 
-The ``synthesize*`` functions in ``synthesizer.py`` remain as thin wrappers
-for backward compatibility; new code should hold a ``SynthesisEngine``.
+A collective is asked for with one
+:class:`repro.core.request.CollectiveRequest` through
+:meth:`SynthesisEngine.collective`; raw conditions go through
+:meth:`SynthesisEngine.synthesize` / :meth:`SynthesisEngine.synthesize_joint`.
 """
 
 from __future__ import annotations
 
 import heapq
-import warnings
 from dataclasses import dataclass, replace
 from typing import Callable, Sequence
 
@@ -32,8 +31,7 @@ from repro.core.algorithm import (CollectiveAlgorithm, Transfer,
 from repro.core.conditions import ChunkIds, Condition, ReduceCondition
 from repro.core.pathfinding import PathResult, bfs_cont, bfs_int
 from repro.core.registry import renumber_chunks
-from repro.core.request import (_UNSET, CollectiveRequest,
-                                PCCLDeprecationWarning)
+from repro.core.request import CollectiveRequest
 from repro.core.ten import TEN
 from repro.topology.topology import Topology
 from repro.tracing import span
@@ -880,13 +878,12 @@ class SynthesisEngine:
         return (h._effective_strategy(),
                 sk.fingerprint() if sk is not None else None)
 
-    # -- named collectives --------------------------------------------------
+    # -- collectives --------------------------------------------------------
 
     def collective(
         self, request: CollectiveRequest, *, ids: ChunkIds | None = None,
     ) -> CollectiveAlgorithm:
-        """Synthesize the collective described by ``request`` — the primary
-        entry point; the named methods below are thin legacy shims over it.
+        """Synthesize the collective described by ``request``.
 
         A request with ``gateway_strategy``/``sketch`` set synthesizes
         through a memoized engine variant configured accordingly (sharing
@@ -988,108 +985,6 @@ class SynthesisEngine:
                                              chunks_per_npu=req.chunks)
         return self._all_reduce_impl(g, bytes=req.bytes,
                                      pipelined=req.pipelined)
-
-    # -- legacy kwarg shims -------------------------------------------------
-
-    def _shim(self, kind, group, explicit, ids, **req_kw):
-        """Common body of the legacy named-collective shims: accept a
-        CollectiveRequest positionally, else build one from the legacy
-        kwargs — warning (with the *caller's* frame blamed) only when a
-        tuning kwarg was explicitly passed, so bare ``eng.all_gather(g)``
-        stays silent sugar."""
-        if isinstance(group, CollectiveRequest):
-            if group.kind != kind:
-                raise ValueError(
-                    f"SynthesisEngine.{kind}() got a {group.kind!r} request")
-            if explicit:
-                raise TypeError(
-                    f"SynthesisEngine.{kind}(): pass tuning in the "
-                    f"CollectiveRequest, not alongside it")
-            return self.collective(group, ids=ids)
-        if explicit:
-            warnings.warn(
-                f"SynthesisEngine.{kind}({', '.join(sorted(explicit))}) "
-                f"kwargs are deprecated; pass a CollectiveRequest to "
-                f"SynthesisEngine.collective()",
-                PCCLDeprecationWarning, stacklevel=3)
-        req = CollectiveRequest(kind, group=tuple(group), **req_kw)
-        return self._collective(req, ids=ids)
-
-    def all_gather(
-        self, group, *, bytes=_UNSET, chunks_per_npu=_UNSET, ids=None,
-        hierarchy=_UNSET,
-    ) -> CollectiveAlgorithm:
-        explicit = {k for k, v in (("bytes", bytes),
-                                   ("chunks_per_npu", chunks_per_npu),
-                                   ("hierarchy", hierarchy))
-                    if v is not _UNSET}
-        return self._shim(
-            "all_gather", group, explicit, ids,
-            bytes=1.0 if bytes is _UNSET else bytes,
-            chunks=1 if chunks_per_npu is _UNSET else chunks_per_npu,
-            hierarchy="auto" if hierarchy is _UNSET else hierarchy)
-
-    def all_to_all(
-        self, group, *, bytes=_UNSET, chunks_per_pair=_UNSET, ids=None,
-        hierarchy=_UNSET,
-    ) -> CollectiveAlgorithm:
-        explicit = {k for k, v in (("bytes", bytes),
-                                   ("chunks_per_pair", chunks_per_pair),
-                                   ("hierarchy", hierarchy))
-                    if v is not _UNSET}
-        return self._shim(
-            "all_to_all", group, explicit, ids,
-            bytes=1.0 if bytes is _UNSET else bytes,
-            chunks=1 if chunks_per_pair is _UNSET else chunks_per_pair,
-            hierarchy="auto" if hierarchy is _UNSET else hierarchy)
-
-    def reduce(
-        self, group, root=None, *, bytes=_UNSET, ids=None,
-    ) -> CollectiveAlgorithm:
-        if isinstance(group, CollectiveRequest):
-            if root is not None:
-                raise TypeError(
-                    "SynthesisEngine.reduce(): root lives in the request")
-            return self._shim("reduce", group, set(), ids)
-        if root is None:
-            raise TypeError("SynthesisEngine.reduce() needs root")
-        explicit = {"bytes"} if bytes is not _UNSET else set()
-        return self._shim(
-            "reduce", group, explicit, ids,
-            bytes=1.0 if bytes is _UNSET else bytes, root=root)
-
-    def reduce_scatter(
-        self, group, *, bytes=_UNSET, chunks_per_npu=_UNSET, ids=None,
-        hierarchy=_UNSET,
-    ) -> CollectiveAlgorithm:
-        explicit = {k for k, v in (("bytes", bytes),
-                                   ("chunks_per_npu", chunks_per_npu),
-                                   ("hierarchy", hierarchy))
-                    if v is not _UNSET}
-        return self._shim(
-            "reduce_scatter", group, explicit, ids,
-            bytes=1.0 if bytes is _UNSET else bytes,
-            chunks=1 if chunks_per_npu is _UNSET else chunks_per_npu,
-            hierarchy="auto" if hierarchy is _UNSET else hierarchy)
-
-    def all_reduce(
-        self, group, *, bytes=_UNSET, ids=None, pipelined=_UNSET,
-        hierarchy=_UNSET,
-    ) -> CollectiveAlgorithm:
-        """All-Reduce = Reduce-Scatter then All-Gather. Pod-spanning groups
-        on partitioned fabrics route hierarchically (both halves composed
-        through the pod-aware pipeline); ``pipelined`` applies to the flat
-        route only — the hierarchical composition runs its phases on the
-        dependency floors derived by ``synthesize_plan``."""
-        explicit = {k for k, v in (("bytes", bytes),
-                                   ("pipelined", pipelined),
-                                   ("hierarchy", hierarchy))
-                    if v is not _UNSET}
-        return self._shim(
-            "all_reduce", group, explicit, ids,
-            bytes=1.0 if bytes is _UNSET else bytes,
-            pipelined=False if pipelined is _UNSET else pipelined,
-            hierarchy="auto" if hierarchy is _UNSET else hierarchy)
 
     # -- reduction internals (paper §4.5, Fig. 8) ---------------------------
 

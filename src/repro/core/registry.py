@@ -2,9 +2,9 @@
 
 Production pods re-synthesize the *same* collectives over and over: every
 data-parallel row of a (data, model) mesh is an isomorphic process group, yet
-each ``synthesize_all_gather(topo, row_i)`` call used to redo the full
-TEN/BFS work. The registry makes synthesized algorithms first-class,
-canonicalized, cached artifacts:
+without a cache each row's all-gather would redo the full TEN/BFS work. The
+registry makes synthesized algorithms first-class, canonicalized, cached
+artifacts:
 
 * **Fingerprint** — ``(topology structure hash, collective kind, canonical
   process group, bytes/chunking params)``.

@@ -1,28 +1,24 @@
 """CollectiveRequest: one frozen value object per collective call.
 
-The engine's named collectives historically grew a kwarg per tuning knob
-(``bytes=``, ``chunks_per_npu=``/``chunks_per_pair=``, ``pipelined=``,
-``hierarchy=``) plus two engine-level settings (``gateway_strategy``,
-``sketch``) that silently changed what the same call meant on different
-engines. :class:`CollectiveRequest` folds all of it into one frozen,
-validated dataclass:
+A request is the one way to ask for a collective. It carries every tuning
+knob (``bytes``, ``chunks``, ``root``, ``hierarchy``, ``pipelined``) and
+the two engine-level settings that a call may override
+(``gateway_strategy``, ``sketch``), and it is validated at construction so
+every layer below can trust it:
 
-* ``SynthesisEngine.collective(request)`` is the primary entry point;
-  ``MeshCollectivePlanner.algorithm(request, ...)`` and
-  ``PlanService.plan(topo, axis_sizes, request, ...)`` accept the same
-  object. The registry route params derive from the request
-  (:meth:`CollectiveRequest.registry_params`), reproducing the legacy
-  tuples bit-for-bit so pre-existing cache entries keep serving.
-* The legacy per-call kwargs survive as thin shims on the named methods;
-  explicitly passing one emits :class:`PCCLDeprecationWarning` (escalated
-  to an error for ``repro``-internal call sites by the pytest config).
+* ``SynthesisEngine.collective(request)`` synthesizes it;
+  ``MeshCollectivePlanner.algorithm``/``program``,
+  ``PlanService.plan`` and ``repro.comms.synthesize_program`` accept the
+  same object.
+* The registry's route params derive from the request
+  (:meth:`CollectiveRequest.registry_params`), in the tuple layout that
+  on-disk cache entries were written under, so they keep serving.
 * ``ids=`` (the caller's chunk-id allocator) stays a call-site argument —
   it is identity-bearing mutable state, not a description of the
   collective, so it never belongs in the frozen request.
 
 ``chunks`` is the per-NPU chunk count for the gather/reduce-scatter family
-and the per-pair count for all_to_all — the one knob the legacy API spelled
-two ways (``chunks_per_npu``/``chunks_per_pair``).
+and the per-pair count for all_to_all.
 """
 
 from __future__ import annotations
@@ -33,23 +29,11 @@ from dataclasses import dataclass, replace
 __all__ = [
     "COLLECTIVE_KINDS",
     "CollectiveRequest",
-    "PCCLDeprecationWarning",
 ]
 
 COLLECTIVE_KINDS = (
     "all_gather", "all_to_all", "reduce", "reduce_scatter", "all_reduce",
 )
-
-# distinguishes "kwarg left at default" from "kwarg explicitly passed" in
-# the legacy shims, so bare eng.all_gather(group) stays warning-free sugar
-_UNSET = object()
-
-
-class PCCLDeprecationWarning(DeprecationWarning):
-    """Deprecation of the per-call kwarg API in favour of
-    :class:`CollectiveRequest`. A dedicated subclass so the test suite can
-    escalate exactly PCCL's own deprecations to errors without tripping
-    over third-party ones."""
 
 
 @dataclass(frozen=True)
@@ -108,9 +92,8 @@ class CollectiveRequest:
         return replace(self, group=tuple(int(n) for n in group))
 
     def registry_params(self, route) -> tuple:
-        """The registry key's params tuple — bit-identical to what the
-        legacy kwarg API produced, so plans cached before the redesign (and
-        across old/new call forms) keep serving.
+        """The registry key's params tuple, in the layout that cached plans
+        (in memory and on disk) were keyed under, so they keep serving.
 
         ``route`` is the resolved hierarchical-route tuple from
         ``SynthesisEngine._route_hierarchical`` (unused for reduce, which

@@ -226,8 +226,7 @@ class MeshCollectivePlanner:
     axis, ``data``-axis groups the first, etc. All groups of one axis are
     isomorphic under the torus translations, so the registry synthesizes each
     (axis, collective, bytes) combination exactly once and serves every other
-    row by relabeling — instead of the old per-row ad-hoc ``synthesize_*``
-    calls.
+    row by relabeling, instead of synthesizing every row anew.
 
     ``axis_sizes`` is an ordered {axis name: size} whose product must equal
     the NPU count; device index = row-major rank, assumed to coincide with
@@ -244,8 +243,8 @@ class MeshCollectivePlanner:
     time-reversal of a hierarchical All-Gather on the reversed fabric, and
     ``all_reduce`` composes that with the forward hierarchical All-Gather —
     so the data-parallel gradient path, the dominant collective of
-    multi-pod training, takes the scalable route by default. Pass
-    ``hierarchy="never"`` to force flat synthesis.
+    multi-pod training, takes the scalable route by default. A request with
+    ``hierarchy="never"`` forces flat synthesis.
 
     Fabrics carrying a nested partition tree (``three_level`` et al —
     rack -> pod -> plane) recurse: a plane-spanning group decomposes into a
@@ -299,44 +298,26 @@ class MeshCollectivePlanner:
         return self.topo.partition_depth + 1
 
     def algorithm(self, kind, axis: str, group_index: int = 0, *,
-                  nbytes: float = 1.0, ids=None, **kw):
+                  nbytes: float = 1.0, ids=None):
         """The synthesized (or registry-served) algorithm for one group.
 
-        ``kind`` is either a collective name or a
+        ``kind`` is either a collective name, asked for at ``nbytes`` with
+        every other field at its default, or a
         :class:`repro.core.request.CollectiveRequest` (its ``group`` is
-        filled in from the axis; other fields pass through). The legacy
-        string form builds the same request internally from ``nbytes`` and
-        the remaining keywords (``chunks_per_npu``/``chunks_per_pair``,
-        ``hierarchy``, ``pipelined``, ``root``).
+        filled in from the axis; other fields pass through, and ``nbytes``
+        is not read).
 
         ``all_gather``/``all_to_all``/``reduce_scatter``/``all_reduce``
         groups that span pods route through the hierarchical pipeline
-        automatically; override with ``hierarchy="never"`` (or
-        "always")."""
+        automatically; a request with ``hierarchy="never"`` (or "always")
+        overrides that."""
         from repro.core.request import CollectiveRequest
 
         group = self.axis_groups(axis)[group_index]
         if isinstance(kind, CollectiveRequest):
-            if kw:
-                raise TypeError(
-                    f"pass request fields on the CollectiveRequest, not as "
-                    f"keywords: {sorted(kw)}")
-            return self.engine.collective(kind.with_group(group), ids=ids)
-        if kind not in ("all_gather", "all_to_all", "all_reduce",
-                        "reduce_scatter", "reduce"):
-            raise ValueError(f"unknown collective kind {kind!r}")
-        chunks = kw.pop("chunks_per_npu", None)
-        if chunks is None:
-            chunks = kw.pop("chunks_per_pair", None)
-        req_kw = {"bytes": nbytes}
-        if chunks is not None:
-            req_kw["chunks"] = chunks
-        for f in ("hierarchy", "pipelined", "root"):
-            if f in kw:
-                req_kw[f] = kw.pop(f)
-        if kw:
-            raise TypeError(f"unknown keyword(s) {sorted(kw)} for {kind}")
-        req = CollectiveRequest(kind, group=tuple(group), **req_kw)
+            req = kind.with_group(group)
+        else:
+            req = CollectiveRequest(kind, group=tuple(group), bytes=nbytes)
         return self.engine.collective(req, ids=ids)
 
     def joint(self, parts, *, name: str = "pccl_joint"):
@@ -391,16 +372,18 @@ class MeshCollectivePlanner:
         ``kind`` is a collective name or a
         :class:`repro.core.request.CollectiveRequest` (group filled in from
         the axis), mirroring :meth:`algorithm` — requests execute any engine
-        route (hierarchy, TE gateways, sketches, pipelining)."""
-        from repro.comms.primitives import CollectiveSpec, synthesize_program
+        route (hierarchy, TE gateways, sketches, pipelining). A name is
+        asked for at ``nbytes``, and an all-reduce pipelined."""
+        from repro.comms.primitives import synthesize_program
         from repro.core.request import CollectiveRequest
 
         group = tuple(self.axis_groups(axis)[group_index])
         if isinstance(kind, CollectiveRequest):
-            spec = kind.with_group(group)
+            req = kind.with_group(group)
         else:
-            spec = CollectiveSpec(kind, group)
+            req = CollectiveRequest(kind, group, bytes=nbytes,
+                                    pipelined=kind == "all_reduce")
         return synthesize_program(
-            self.topo, spec, nbytes=nbytes, registry=self.registry,
+            self.topo, req, registry=self.registry,
             device_of_npu=device_of_npu,
         )

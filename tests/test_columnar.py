@@ -18,6 +18,7 @@ import pytest
 from repro.core import (
     AlgorithmRegistry,
     CollectiveAlgorithm,
+    CollectiveRequest,
     CommSketch,
     SynthesisEngine,
     Transfer,
@@ -52,14 +53,22 @@ def _routes():
         registry=AlgorithmRegistry(),
         sketch=CommSketch(max_pod_ports={0: 1, 1: 1}))
     return [
-        ("flat_ag", flat.all_gather(list(range(16)))),
-        ("flat_a2a", flat.all_to_all([0, 1, 2, 3])),
-        ("flat_rs", flat.reduce_scatter(list(range(16)))),
-        ("hier_ag", pods.all_gather(pods.topology.npus)),
-        ("hier_ar", pods.all_reduce(pods.topology.npus)),
-        ("hier3_ag", deep.all_gather(deep.topology.npus)),
-        ("hier3_rs", deep.reduce_scatter(deep.topology.npus)),
-        ("te_ag", te.all_gather(te.topology.npus)),
+        ("flat_ag", flat.collective(CollectiveRequest(
+            "all_gather", list(range(16))))),
+        ("flat_a2a", flat.collective(CollectiveRequest(
+            "all_to_all", [0, 1, 2, 3]))),
+        ("flat_rs", flat.collective(CollectiveRequest(
+            "reduce_scatter", list(range(16))))),
+        ("hier_ag", pods.collective(CollectiveRequest(
+            "all_gather", pods.topology.npus))),
+        ("hier_ar", pods.collective(CollectiveRequest(
+            "all_reduce", pods.topology.npus))),
+        ("hier3_ag", deep.collective(CollectiveRequest(
+            "all_gather", deep.topology.npus))),
+        ("hier3_rs", deep.collective(CollectiveRequest(
+            "reduce_scatter", deep.topology.npus))),
+        ("te_ag", te.collective(CollectiveRequest(
+            "all_gather", te.topology.npus))),
     ]
 
 
@@ -166,7 +175,8 @@ class TestScheduleIdentity:
 class TestTransferListApi:
     def setup_method(self):
         eng = SynthesisEngine(torus2d(3, 3), registry=AlgorithmRegistry())
-        self.alg = eng.all_gather(list(range(9)))
+        self.alg = eng.collective(CollectiveRequest(
+            "all_gather", list(range(9))))
         self.tl = self.alg.transfers
 
     def test_sequence_semantics(self):

@@ -14,15 +14,15 @@ import numpy as np
 import pytest
 
 from repro.comms.executor import plan_buffers
-from repro.core import synthesize_all_gather, synthesize_all_to_all, to_ppermute_program
-from repro.core.synthesizer import synthesize_all_reduce
+from repro.core import CollectiveRequest, SynthesisEngine, to_ppermute_program
 from repro.topology import ring, torus2d
 
 
 class TestTranslation:
     def test_rounds_are_permutations(self):
         topo = torus2d(3, 3)
-        alg = synthesize_all_to_all(topo, list(range(9)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(9))))
         prog = to_ppermute_program(alg)
         for rnd in prog.rounds:
             srcs = [s.src for s in rnd]
@@ -32,7 +32,8 @@ class TestTranslation:
 
     def test_rounds_preserve_transfer_count(self):
         topo = ring(6, bidirectional=True)
-        alg = synthesize_all_gather(topo, list(range(6)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(6))))
         prog = to_ppermute_program(alg)
         assert sum(len(r) for r in prog.rounds) == alg.num_transfers
 
@@ -40,7 +41,8 @@ class TestTranslation:
         """A chunk is never sent by a device before a round in which that
         device held/received it."""
         topo = torus2d(3, 3)
-        alg = synthesize_all_reduce(topo, list(range(9)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_reduce", list(range(9))))
         prog = to_ppermute_program(alg)
         holders = {c: set(h) for c, h in prog.chunk_holders.items()}
         for rnd in prog.rounds:
@@ -51,7 +53,8 @@ class TestTranslation:
 
     def test_buffer_plan_slots(self):
         topo = ring(4, bidirectional=True)
-        alg = synthesize_all_gather(topo, list(range(4)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         prog = to_ppermute_program(alg)
         plan = plan_buffers(prog)
         assert plan.num_slots >= 4  # every device ends with all 4 chunks
@@ -168,7 +171,8 @@ class TestSwitchUnrolling:
 class TestPlanCache:
     def _prog(self, n):
         topo = ring(n, bidirectional=True)
-        alg = synthesize_all_gather(topo, list(range(n)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(n))))
         return to_ppermute_program(alg)
 
     def test_colliding_fingerprints_do_not_cross_serve(self):

@@ -2,7 +2,7 @@
 MoE expert-parallel dispatch and previously had no coverage."""
 
 
-from repro.core import ChunkIds, all_to_allv, synthesize
+from repro.core import ChunkIds, SynthesisEngine, all_to_allv
 from repro.topology import ring, torus2d
 
 
@@ -67,7 +67,7 @@ class TestAllToAllv:
         counts = {(i, j): (i + j) % 3 for i in range(9) for j in range(9)
                   if i != j}
         conds = all_to_allv(list(range(9)), counts)
-        alg = synthesize(topo, conds)
+        alg = SynthesisEngine(topo).synthesize(conds)
         alg.validate()
         delivered = {c.chunk for c in alg.conditions}
         assert len(delivered) == sum(counts.values())
@@ -80,6 +80,6 @@ class TestAllToAllv:
     def test_ring_delivery(self):
         topo = ring(4)
         conds = all_to_allv([0, 1, 2, 3], {(0, 2): 2, (3, 1): 1})
-        alg = synthesize(topo, conds)
+        alg = SynthesisEngine(topo).synthesize(conds)
         alg.validate()
         assert alg.makespan >= 2.0  # two hops minimum on the ring

@@ -11,13 +11,11 @@ from hypothesis import given, settings  # noqa: E402
 
 from repro.core import (
     ChunkIds,
+    CollectiveRequest,
     Condition,
+    SynthesisEngine,
     all_gather,
     all_to_all,
-    synthesize,
-    synthesize_all_reduce,
-    synthesize_joint,
-    synthesize_reduce_scatter,
 )
 from repro.topology.topology import NodeType, Topology
 
@@ -85,7 +83,7 @@ def groups_of(draw, topo):
 def test_all_gather_valid_on_random_topology(data):
     topo = data.draw(connected_topologies())
     group = data.draw(groups_of(topo))
-    alg = synthesize(topo, all_gather(list(group)))
+    alg = SynthesisEngine(topo).synthesize(all_gather(list(group)))
     alg.validate()
 
 
@@ -94,7 +92,7 @@ def test_all_gather_valid_on_random_topology(data):
 def test_all_to_all_valid_on_random_topology(data):
     topo = data.draw(connected_topologies(max_npus=6))
     group = data.draw(groups_of(topo))
-    alg = synthesize(topo, all_to_all(list(group)))
+    alg = SynthesisEngine(topo).synthesize(all_to_all(list(group)))
     alg.validate()
 
 
@@ -104,7 +102,8 @@ def test_hetero_random_topology(data):
     topo = data.draw(connected_topologies(max_npus=6, hetero=True))
     group = data.draw(groups_of(topo))
     bytes_ = data.draw(st.sampled_from([0.5, 1.0, 3.0]))
-    alg = synthesize(topo, all_gather(list(group), bytes=bytes_))
+    alg = SynthesisEngine(topo).synthesize(
+        all_gather(list(group), bytes=bytes_))
     alg.validate()
 
 
@@ -113,7 +112,7 @@ def test_hetero_random_topology(data):
 def test_switch_random_topology(data):
     topo = data.draw(connected_topologies(max_npus=6, switches=True))
     group = data.draw(groups_of(topo))
-    alg = synthesize(topo, all_gather(list(group)))
+    alg = SynthesisEngine(topo).synthesize(all_gather(list(group)))
     alg.validate()
 
 
@@ -122,10 +121,11 @@ def test_switch_random_topology(data):
 def test_reductions_random_topology(data):
     topo = data.draw(connected_topologies(max_npus=6))
     group = data.draw(groups_of(topo))
-    rs = synthesize_reduce_scatter(topo, list(group))
+    rs = SynthesisEngine(topo).collective(CollectiveRequest(
+        "reduce_scatter", list(group)))
     rs.validate()
-    ar = synthesize_all_reduce(topo, list(group),
-                               pipelined=data.draw(st.booleans()))
+    ar = SynthesisEngine(topo).collective(CollectiveRequest(
+        "all_reduce", list(group), pipelined=data.draw(st.booleans())))
     ar.validate()
 
 
@@ -139,10 +139,8 @@ def test_joint_groups_random(data):
     half = len(npus) // 2
     ids = ChunkIds()
     g1, g2 = npus[:half], npus[half:]
-    alg = synthesize_joint(
-        topo,
-        [("g1", all_gather(g1, ids=ids)), ("g2", all_to_all(g2, ids=ids))],
-    )
+    alg = SynthesisEngine(topo).synthesize_joint(
+        [("g1", all_gather(g1, ids=ids)), ("g2", all_to_all(g2, ids=ids))])
     alg.validate()
 
 
@@ -162,7 +160,7 @@ def test_arbitrary_conditions_random(data):
                      unique=True)
         )
         conds.append(Condition(ids.next(), src, frozenset(dests)))
-    alg = synthesize(topo, conds)
+    alg = SynthesisEngine(topo).synthesize(conds)
     alg.validate()
     # postcondition double-check outside the oracle
     for c in conds:

@@ -4,7 +4,9 @@ import pytest
 
 from repro.core import (
     ChunkIds,
+    CollectiveRequest,
     Condition,
+    SynthesisEngine,
     all_gather,
     all_to_all,
     all_to_allv,
@@ -14,13 +16,6 @@ from repro.core import (
     multicast,
     point_to_point,
     scatter,
-    synthesize,
-    synthesize_all_gather,
-    synthesize_all_reduce,
-    synthesize_all_to_all,
-    synthesize_joint,
-    synthesize_reduce,
-    synthesize_reduce_scatter,
     order_conditions,
 )
 from repro.core.pathfinding import bfs_cont, bfs_int
@@ -159,7 +154,7 @@ class TestConditionBuilders:
         v_ids, ag_ids = ChunkIds().split(2)
         v = all_to_all([0, 1, 2], ids=v_ids)
         ag = all_gather([6, 7, 8], ids=ag_ids)
-        alg = synthesize_joint(topo, [("a2a", v), ("ag", ag)])
+        alg = SynthesisEngine(topo).synthesize_joint([("a2a", v), ("ag", ag)])
         alg.validate()
 
     def test_ordering_longest_first(self):
@@ -176,19 +171,22 @@ class TestSynthesis:
     def test_ring_all_gather_optimal(self):
         # paper Fig 3a: unidirectional ring AG in exactly n-1 steps
         for n in (3, 4, 7):
-            alg = synthesize_all_gather(ring(n), list(range(n)))
+            alg = SynthesisEngine(ring(n)).collective(CollectiveRequest(
+                "all_gather", list(range(n))))
             alg.validate()
             assert alg.makespan == n - 1
 
     def test_all_gather_every_topology(self):
         for topo in (line(5), mesh2d(3, 4), torus2d(3, 3), hypercube(3)):
             group = topo.npus
-            alg = synthesize_all_gather(topo, group)
+            alg = SynthesisEngine(topo).collective(CollectiveRequest(
+                "all_gather", group))
             alg.validate()
 
     def test_all_to_all_mesh(self):
         topo = mesh2d(4, 4)
-        alg = synthesize_all_to_all(topo, list(range(16)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(16))))
         alg.validate()
         # beats Direct baseline on the same topology (paper Fig 14)
         direct = direct_all_to_all(topo, list(range(16)))
@@ -201,13 +199,14 @@ class TestSynthesis:
             gather(list(range(9)), 0),
             broadcast(list(range(9)), 8),
         ):
-            alg = synthesize(topo, conds)
+            alg = SynthesisEngine(topo).synthesize(conds)
             alg.validate()
 
     def test_process_group_uses_outside_links(self):
         # AG among 3 corner NPUs of a 3x3 mesh must route via others
         topo = mesh2d(3, 3)
-        alg = synthesize_all_gather(topo, [0, 2, 8])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", [0, 2, 8]))
         alg.validate()
         touched = {t.src for t in alg.transfers} | {t.dst for t in alg.transfers}
         assert touched - {0, 2, 8}, "expected out-of-group forwarding"
@@ -215,7 +214,7 @@ class TestSynthesis:
     def test_release_times_respected(self):
         topo = ring(4)
         conds = [Condition(0, 0, frozenset([1]), release=5.0)]
-        alg = synthesize(topo, conds)
+        alg = SynthesisEngine(topo).synthesize(conds)
         alg.validate()
         assert alg.transfers[0].start >= 5.0
 
@@ -225,7 +224,7 @@ class TestSynthesis:
         ids = ChunkIds()
         v = all_to_allv([0, 1, 2], [[0, 2, 2], [1, 0, 1], [1, 1, 0]], ids=ids)
         ag = all_gather([6, 7, 8], ids=ids)
-        alg = synthesize_joint(topo, [("pg0", v), ("pg1", ag)])
+        alg = SynthesisEngine(topo).synthesize_joint([("pg0", v), ("pg1", ag)])
         alg.validate()
 
     def test_joint_duplicate_chunks_rejected(self):
@@ -233,62 +232,73 @@ class TestSynthesis:
         a = all_gather([0, 1])  # fresh ids starting at 0
         b = all_gather([2, 3])  # also starting at 0 -> collision
         with pytest.raises(ValueError):
-            synthesize_joint(topo, [("a", a), ("b", b)])
+            SynthesisEngine(topo).synthesize_joint([("a", a), ("b", b)])
 
 
 class TestReductions:
     def test_reduce(self):
         topo = mesh2d(3, 3)
-        alg = synthesize_reduce(topo, list(range(9)), root=4)
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "reduce", list(range(9)), root=4))
         alg.validate()
 
     def test_reduce_scatter(self):
         for topo in (ring(4, bidirectional=True), mesh2d(3, 3), hypercube(3)):
-            alg = synthesize_reduce_scatter(topo, topo.npus)
+            alg = SynthesisEngine(topo).collective(CollectiveRequest(
+                "reduce_scatter", topo.npus))
             alg.validate()
 
     def test_all_reduce(self):
         topo = ring(8, bidirectional=True)
-        alg = synthesize_all_reduce(topo, list(range(8)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_reduce", list(range(8))))
         alg.validate()
 
     def test_all_reduce_pipelined_not_slower(self):
         topo = mesh2d(4, 4)
-        base = synthesize_all_reduce(topo, list(range(16)), pipelined=False)
-        pipe = synthesize_all_reduce(topo, list(range(16)), pipelined=True)
+        base = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_reduce", list(range(16)), pipelined=False))
+        pipe = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_reduce", list(range(16)), pipelined=True))
         base.validate()
         pipe.validate()
         assert pipe.makespan <= base.makespan
 
     def test_reduce_process_group(self):
         topo = mesh2d(3, 3)
-        alg = synthesize_reduce_scatter(topo, [0, 4, 8])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "reduce_scatter", [0, 4, 8]))
         alg.validate()
 
 
 class TestSwitches:
     def test_star_switch_all_gather(self):
         topo = star_switch(4)
-        alg = synthesize_all_gather(topo, [0, 1, 2, 3])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]))
         alg.validate()
 
     def test_star_switch_no_multicast_serializes(self):
         topo = star_switch(4, multicast=False)
-        alg = synthesize_all_gather(topo, [0, 1, 2, 3])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]))
         alg.validate()
         mc = star_switch(4, multicast=True)
-        alg_mc = synthesize_all_gather(mc, [0, 1, 2, 3])
+        alg_mc = SynthesisEngine(mc).collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]))
         alg_mc.validate()
         assert alg.makespan >= alg_mc.makespan
 
     def test_buffer_limit_respected(self):
         topo = star_switch(6, buffer_limit=1)
-        alg = synthesize_all_to_all(topo, list(range(6)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(6))))
         alg.validate()  # validator enforces the limit
 
     def test_two_level_switch_hetero(self):
         topo = two_level_switch(2, npus_per_node=4)
-        alg = synthesize_all_to_all(topo, list(range(8)), bytes=512.0)
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(8)), bytes=512.0))
         alg.validate()
         # intra-node chunks finish before cross-node ones on average
         intra = [t for t in alg.transfers if t.start == 0.0]
@@ -302,7 +312,8 @@ class TestHeterogeneous:
         topo.add_npus(3)
         topo.add_link(0, 1, alpha=2.0, beta=0.5)
         topo.add_link(1, 2, alpha=1.0, beta=2.0)
-        alg = synthesize(topo, [Condition(0, 0, frozenset([2]), bytes=4.0)])
+        alg = SynthesisEngine(topo).synthesize(
+            [Condition(0, 0, frozenset([2]), bytes=4.0)])
         alg.validate()
         # 0->1: 2 + 4*0.5 = 4; 1->2: 1 + 4*2 = 9 => makespan 13
         assert alg.makespan == pytest.approx(13.0)
@@ -316,7 +327,7 @@ class TestHeterogeneous:
             Condition(0, 0, frozenset([1]), bytes=2.0),
             Condition(1, 0, frozenset([1]), bytes=2.0),
         ]
-        alg = synthesize(topo, conds)
+        alg = SynthesisEngine(topo).synthesize(conds)
         alg.validate()
         spans = sorted((t.start, t.end) for t in alg.transfers)
         assert spans[0][1] <= spans[1][0] + 1e-9
@@ -325,8 +336,8 @@ class TestHeterogeneous:
     def test_fast_path_equals_slow_path(self):
         topo = mesh2d(3, 3)
         conds = all_to_all(list(range(9)))
-        fast = synthesize(topo, conds, mode="int")
-        slow = synthesize(topo, conds, mode="cont")
+        fast = SynthesisEngine(topo).synthesize(conds, mode="int")
+        slow = SynthesisEngine(topo).synthesize(conds, mode="cont")
         fast.validate()
         slow.validate()
         assert fast.makespan == pytest.approx(slow.makespan)

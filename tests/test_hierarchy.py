@@ -109,7 +109,7 @@ class TestPhasePlan:
     def test_all_reduce_still_composes(self):
         # the refactor of all-reduce onto PhasePlan keeps its contract
         eng = SynthesisEngine(ring(4))
-        alg = eng.all_reduce(list(range(4)))
+        alg = eng.collective(CollectiveRequest("all_reduce", list(range(4))))
         alg.validate()
         assert [n for n, _, _ in alg.phase_spans] == \
             ["reduce_scatter", "all_gather"]
@@ -126,7 +126,7 @@ class TestDifferentialEquivalence:
     @pytest.mark.parametrize("kind", ["all_gather", "all_to_all"])
     def test_chunk_delivery_equivalence(self, fabric, kind):
         eng = SynthesisEngine(fabric, registry=AlgorithmRegistry())
-        hier = getattr(eng, kind)(fabric.npus)
+        hier = eng.collective(CollectiveRequest(kind, fabric.npus))
         flat = eng.collective(CollectiveRequest(
             kind, group=tuple(fabric.npus), hierarchy="never"))
         assert hier.name.startswith("pccl_hier")
@@ -140,7 +140,7 @@ class TestDifferentialEquivalence:
     @pytest.mark.parametrize("kind", ["all_gather", "all_to_all"])
     def test_makespan_within_bound(self, fabric, kind):
         eng = SynthesisEngine(fabric, registry=AlgorithmRegistry())
-        hier = getattr(eng, kind)(fabric.npus)
+        hier = eng.collective(CollectiveRequest(kind, fabric.npus))
         flat = eng.collective(CollectiveRequest(
             kind, group=tuple(fabric.npus), hierarchy="never"))
         assert hier.makespan <= _MAKESPAN_BOUND * flat.makespan, (
@@ -161,7 +161,7 @@ class TestFabricFamilies:
     def test_heterogeneous_multi_pod(self):
         topo = multi_pod(2, 4, 4, dci_ports_per_pod=4)  # real alpha-beta
         eng = SynthesisEngine(topo)
-        alg = eng.all_gather(topo.npus)
+        alg = eng.collective(CollectiveRequest("all_gather", topo.npus))
         assert alg.name == "pccl_hier_all_gather"
         alg.validate()
 
@@ -179,7 +179,7 @@ class TestFabricFamilies:
         topo = grid_hypercube(4, 3)  # 64 NPUs, 4 plane-pods, no switch
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
         for kind in ("all_gather", "all_to_all"):
-            alg = getattr(eng, kind)(topo.npus)
+            alg = eng.collective(CollectiveRequest(kind, topo.npus))
             assert alg.name.startswith("pccl_hier")
             alg.validate()
 
@@ -187,20 +187,21 @@ class TestFabricFamilies:
         topo = multi_pod(2, 4, 8, unit_links=True)
         group = list(range(8, 24)) + list(range(40, 56))  # interior rows
         eng = SynthesisEngine(topo)
-        alg = eng.all_gather(group)
+        alg = eng.collective(CollectiveRequest("all_gather", group))
         alg.validate()
         assert len(alg.conditions) == len(group)
 
     def test_single_pod_group_stays_flat(self):
         topo = multi_pod(2, 4, 8, unit_links=True)
         eng = SynthesisEngine(topo)
-        alg = eng.all_gather(list(range(32)))  # pod 0 only
+        alg = eng.collective(CollectiveRequest(
+            "all_gather", list(range(32))))  # pod 0 only
         assert alg.name == "pccl_all_gather"
         alg.validate()
 
     def test_unpartitioned_fabric_stays_flat(self):
         eng = SynthesisEngine(ring(8))
-        alg = eng.all_to_all(list(range(8)))
+        alg = eng.collective(CollectiveRequest("all_to_all", list(range(8))))
         assert alg.name == "pccl_all_to_all"
         with pytest.raises(HierarchyError):
             HierarchicalSynthesizer(eng).all_to_all(list(range(8)))
@@ -261,7 +262,7 @@ class TestHierarchicalReductions:
 
     def test_reduce_scatter_matches_oracle_state(self, fabric):
         eng = SynthesisEngine(fabric, registry=AlgorithmRegistry())
-        hier = eng.reduce_scatter(fabric.npus)
+        hier = eng.collective(CollectiveRequest("reduce_scatter", fabric.npus))
         assert hier.name == "pccl_hier_reduce_scatter"
         hier.validate(mode="oracle")
         assert all(t.reduce for t in hier.transfers)
@@ -282,7 +283,7 @@ class TestHierarchicalReductions:
 
     def test_all_reduce_composes_rs_then_ag(self, fabric):
         eng = SynthesisEngine(fabric, registry=AlgorithmRegistry())
-        alg = eng.all_reduce(fabric.npus)
+        alg = eng.collective(CollectiveRequest("all_reduce", fabric.npus))
         assert alg.name == "pccl_hier_all_reduce"
         alg.validate(mode="oracle")
         assert [n for n, _, _ in alg.top_phase_spans()] == \
@@ -297,7 +298,7 @@ class TestHierarchicalReductions:
     @pytest.mark.parametrize("kind", ["reduce_scatter", "all_reduce"])
     def test_makespan_not_worse_than_flat(self, fabric, kind):
         eng = SynthesisEngine(fabric, registry=AlgorithmRegistry())
-        hier = getattr(eng, kind)(fabric.npus)
+        hier = eng.collective(CollectiveRequest(kind, fabric.npus))
         flat = eng.collective(CollectiveRequest(
             kind, group=tuple(fabric.npus), hierarchy="never"))
         assert hier.makespan <= flat.makespan, (
@@ -339,7 +340,7 @@ class TestHierarchicalReductions:
         topo = grid_hypercube(4, 3)  # 64 NPUs, 4 plane-pods, no switch
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
         for kind in ("reduce_scatter", "all_reduce"):
-            alg = getattr(eng, kind)(topo.npus)
+            alg = eng.collective(CollectiveRequest(kind, topo.npus))
             assert alg.name.startswith("pccl_hier")
             alg.validate()
 
@@ -349,7 +350,8 @@ class TestHierarchicalReductions:
         # partials, so the in-forest guard routes reductions to flat
         topo = two_level_switch(3, npus_per_node=4)
         eng = SynthesisEngine(topo)
-        alg = eng.reduce_scatter(list(range(12)))
+        alg = eng.collective(CollectiveRequest(
+            "reduce_scatter", list(range(12))))
         assert alg.name == "pccl_reduce_scatter"
         alg.validate()
         with pytest.raises(HierarchyError, match="in-forest"):
@@ -358,13 +360,14 @@ class TestHierarchicalReductions:
     def test_subgroup_spanning_pods(self, fabric):
         group = list(range(8, 24)) + list(range(40, 56))
         eng = SynthesisEngine(fabric)
-        alg = eng.all_reduce(group)
+        alg = eng.collective(CollectiveRequest("all_reduce", group))
         assert alg.name == "pccl_hier_all_reduce"
         alg.validate(mode="oracle")
 
     def test_single_pod_group_stays_flat(self, fabric):
         eng = SynthesisEngine(fabric)
-        alg = eng.reduce_scatter(list(range(32)))  # pod 0 only
+        alg = eng.collective(CollectiveRequest(
+            "reduce_scatter", list(range(32))))  # pod 0 only
         assert alg.name == "pccl_reduce_scatter"
         alg.validate()
 
@@ -409,8 +412,10 @@ class TestPathReplication:
     def test_flat_default_unchanged(self):
         # replicate defaults off: flat named collectives are byte-stable
         topo = ring(5)
-        a = SynthesisEngine(topo).all_to_all(list(range(5)))
-        b = SynthesisEngine(topo).all_to_all(list(range(5)))
+        a = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(5))))
+        b = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(5))))
         assert [(t.chunk, t.link, t.start) for t in a.transfers] == \
             [(t.chunk, t.link, t.start) for t in b.transfers]
 
@@ -448,7 +453,8 @@ class TestHierarchyAlwaysPolicy:
         topo = two_level_switch(3, npus_per_node=4)
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
         group = list(range(12))
-        auto = eng.reduce_scatter(group)  # in-forest guard -> flat fallback
+        auto = eng.collective(CollectiveRequest(
+            "reduce_scatter", group))  # in-forest guard -> flat fallback
         assert auto.name == "pccl_reduce_scatter"
         with pytest.raises(HierarchyError):
             eng.collective(CollectiveRequest(

@@ -59,7 +59,8 @@ def _routes(eng, kind, group):
     routes = {
         "flat": eng.collective(
             CollectiveRequest(kind, group=tuple(group), hierarchy="never")),
-        "hier": getattr(eng, kind)(group),  # auto: pipelined where safe
+        "hier": eng.collective(CollectiveRequest(
+            kind, group)),  # auto: pipelined where safe
     }
     if kind == "all_reduce":
         routes["flat_pipelined"] = eng.collective(CollectiveRequest(
@@ -107,7 +108,7 @@ class TestRoutingPathEquivalence:
         suite into flat-vs-flat."""
         topo = FABRICS[fabric_name]()
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
-        alg = getattr(eng, kind)(topo.npus)
+        alg = eng.collective(CollectiveRequest(kind, topo.npus))
         assert alg.name.startswith("pccl_hier")
 
 
@@ -127,11 +128,10 @@ class TestTwoVsThreeLevel:
     @pytest.mark.parametrize("kind", KINDS)
     def test_depth_views_agree(self, kind):
         shallow, deep = self._views()
-        a2 = getattr(SynthesisEngine(shallow,
-                                     registry=AlgorithmRegistry()),
-                     kind)(shallow.npus)
-        a3 = getattr(SynthesisEngine(deep, registry=AlgorithmRegistry()),
-                     kind)(deep.npus)
+        a2 = SynthesisEngine(shallow, registry=AlgorithmRegistry()).collective(
+            CollectiveRequest(kind, shallow.npus))
+        a3 = SynthesisEngine(deep, registry=AlgorithmRegistry()).collective(
+            CollectiveRequest(kind, deep.npus))
         assert _delivery(a2) == _delivery(a3)
         for alg in (a2, a3):
             alg.validate(mode="oracle")
@@ -139,13 +139,14 @@ class TestTwoVsThreeLevel:
 
     def test_three_level_view_recurses(self):
         _, deep = self._views()
-        alg = SynthesisEngine(deep).all_gather(deep.npus)
+        alg = SynthesisEngine(deep).collective(CollectiveRequest(
+            "all_gather", deep.npus))
         nested = [n for n, _, _ in alg.phase_spans if "/" in n]
         assert any(n.startswith("intra:") and "/inter" in n for n in nested), (
             "3-level view must decompose pod intra phases into nested "
             "rack/boundary phases")
-        shallow_alg = SynthesisEngine(self._views()[0]).all_gather(
-            deep.npus)
+        shallow_alg = SynthesisEngine(self._views()[0]).collective(CollectiveRequest(
+            "all_gather", deep.npus))
         assert not any("/" in n for n, _, _ in shallow_alg.phase_spans)
 
 
@@ -171,9 +172,11 @@ class TestPartitionTreeRegistryKeys:
         shallow = three_level(2, 2, 3, unit_links=True)
         shallow.set_partition([p[0] for p in deep.partition_paths])
         reg = AlgorithmRegistry()
-        getattr(SynthesisEngine(shallow, registry=reg), kind)(shallow.npus)
+        SynthesisEngine(shallow, registry=reg).collective(
+            CollectiveRequest(kind, shallow.npus))
         misses = reg.stats.misses
-        alg = getattr(SynthesisEngine(deep, registry=reg), kind)(deep.npus)
+        alg = SynthesisEngine(deep, registry=reg).collective(
+            CollectiveRequest(kind, deep.npus))
         assert reg.stats.misses > misses, (
             f"{kind}: the 3-level view was served the cached 2-level plan")
         alg.validate()
@@ -267,7 +270,7 @@ class TestPipelinedAllReduceJunction:
         except HierarchyError:
             # switch-boundary fabrics refuse the forced pipeline; the auto
             # engine route resolves the regime itself and must agree
-            pipe = eng.all_reduce(topo.npus)
+            pipe = eng.collective(CollectiveRequest("all_reduce", topo.npus))
         assert _delivery(pipe) == _delivery(barrier)
         for alg in (pipe, barrier):
             alg.validate(mode="bulk")

@@ -114,7 +114,8 @@ def check_synthesis_seed(seed: int) -> None:
         hier = None  # the legal refusal: fall back flat below
     if hier is not None:
         hier.validate(mode="oracle")
-    auto = getattr(eng, kind)(group)  # auto route: hier or flat fallback
+    auto = eng.collective(CollectiveRequest(
+        kind, group))  # auto route: hier or flat fallback
     auto.validate(mode="oracle")
     flat = eng.collective(
         CollectiveRequest(kind, group=tuple(group), hierarchy="never"))
@@ -214,7 +215,7 @@ def check_corruption_seed(seed: int) -> None:
     topo = _gen_fabric(rng)
     eng = SynthesisEngine(topo, registry=AlgorithmRegistry())
     kind = rng.choice(["all_gather", "all_to_all"])
-    alg = getattr(eng, kind)(topo.npus)
+    alg = eng.collective(CollectiveRequest(kind, topo.npus))
     alg.validate(mode="bulk")  # the uncorrupted schedule passes
     bad = _corrupt(alg, rng)
     if bad is None:

@@ -6,7 +6,7 @@ gate for the array-backed synthesis core)."""
 
 import pytest
 
-from repro.core import all_gather, all_to_all
+from repro.core import CollectiveRequest, all_gather, all_to_all
 from repro.core.conditions import ChunkIds, Condition
 from repro.core.engine import SynthesisEngine
 from repro.core.pathfinding import bfs_cont, bfs_int, bfs_int_ref
@@ -95,11 +95,13 @@ def test_synthesized_algorithms_identical():
 
     topo = mesh2d(4, 4)
     group = list(range(16))
-    new_alg = SynthesisEngine(topo).all_to_all(group)
+    new_alg = SynthesisEngine(topo).collective(CollectiveRequest(
+        "all_to_all", group))
     orig = eng.bfs_int
     eng.bfs_int = bfs_int_ref
     try:
-        ref_alg = SynthesisEngine(topo).all_to_all(group)
+        ref_alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", group))
     finally:
         eng.bfs_int = orig
     assert new_alg.transfers == ref_alg.transfers

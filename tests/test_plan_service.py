@@ -139,7 +139,7 @@ def test_shared_cache_dir_concurrent_readers_one_writer(tmp_path):
     # the survivors on disk are valid, loadable entries
     reg = AlgorithmRegistry(cache_dir=str(cache))
     eng = SynthesisEngine(torus2d(4, 4), registry=reg)
-    eng.all_gather([0, 1, 2, 3]).validate()
+    eng.collective(CollectiveRequest("all_gather", [0, 1, 2, 3])).validate()
 
 
 class TestDiskEviction:

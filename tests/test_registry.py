@@ -16,8 +16,6 @@ from repro.core import (
     enumerate_automorphisms,
     from_msccl_json,
     is_automorphism,
-    synthesize_all_gather,
-    synthesize_joint,
     to_msccl_json,
     topology_fingerprint,
 )
@@ -114,7 +112,7 @@ class TestRegistry:
         eng = SynthesisEngine(topo, registry=reg)
         rows = torus_rows(4, 4)
 
-        cold = eng.all_gather(rows[0])
+        cold = eng.collective(CollectiveRequest("all_gather", rows[0]))
         cold.validate()
         assert reg.stats.misses == 1
 
@@ -124,7 +122,7 @@ class TestRegistry:
         monkeypatch.setattr(engine_mod, "bfs_int", boom)
         monkeypatch.setattr(engine_mod, "bfs_cont", boom)
         for row in rows[1:]:
-            alg = eng.all_gather(row)
+            alg = eng.collective(CollectiveRequest("all_gather", row))
             alg.validate()
             assert alg.makespan == cold.makespan
             # delivered to the requested group, not the canonical one
@@ -137,12 +135,14 @@ class TestRegistry:
         topo = torus2d(4, 4)
         reg = AlgorithmRegistry()
         eng = SynthesisEngine(topo, registry=reg)
-        eng.all_gather(torus_rows(4, 4)[0])
+        eng.collective(CollectiveRequest("all_gather", torus_rows(4, 4)[0]))
         eng.collective(CollectiveRequest(
             "all_gather", group=tuple(torus_rows(4, 4)[0]),
             bytes=2.0))  # different params
-        eng.all_to_all(torus_rows(4, 4)[0])  # different kind
-        eng.all_gather([0, 5, 10, 15])  # diagonal: different canonical group
+        eng.collective(CollectiveRequest(
+            "all_to_all", torus_rows(4, 4)[0]))  # different kind
+        eng.collective(CollectiveRequest(
+            "all_gather", [0, 5, 10, 15]))  # diagonal: different canonical group
         assert reg.stats.misses == 4
 
     def test_reductions_and_allreduce_cached(self):
@@ -150,10 +150,10 @@ class TestRegistry:
         reg = AlgorithmRegistry()
         eng = SynthesisEngine(topo, registry=reg)
         rows = torus_rows(4, 4)
-        cold_rs = eng.reduce_scatter(rows[0])
+        cold_rs = eng.collective(CollectiveRequest("reduce_scatter", rows[0]))
         cold_ar = eng.collective(CollectiveRequest(
             "all_reduce", group=tuple(rows[0]), pipelined=True))
-        hit_rs = eng.reduce_scatter(rows[3])
+        hit_rs = eng.collective(CollectiveRequest("reduce_scatter", rows[3]))
         hit_ar = eng.collective(CollectiveRequest(
             "all_reduce", group=tuple(rows[3]), pipelined=True))
         for alg in (cold_rs, cold_ar, hit_rs, hit_ar):
@@ -167,7 +167,8 @@ class TestRegistry:
         reg = AlgorithmRegistry()
         eng = SynthesisEngine(topo, registry=reg)
         ids = ChunkIds(100)
-        alg = eng.all_gather(torus_rows(4, 4)[1], ids=ids)
+        alg = eng.collective(CollectiveRequest(
+            "all_gather", torus_rows(4, 4)[1]), ids=ids)
         assert sorted(c.chunk for c in alg.conditions) == list(range(100, 104))
         alg.validate()
 
@@ -175,9 +176,11 @@ class TestRegistry:
         topo = torus2d(4, 4)
         reg = AlgorithmRegistry(max_entries=1)
         eng = SynthesisEngine(topo, registry=reg)
-        eng.all_gather(torus_rows(4, 4)[0])
-        eng.all_to_all(torus_rows(4, 4)[0])  # evicts the all_gather
-        eng.all_gather(torus_rows(4, 4)[0])  # re-synthesizes
+        eng.collective(CollectiveRequest("all_gather", torus_rows(4, 4)[0]))
+        eng.collective(CollectiveRequest(
+            "all_to_all", torus_rows(4, 4)[0]))  # evicts the all_gather
+        eng.collective(CollectiveRequest(
+            "all_gather", torus_rows(4, 4)[0]))  # re-synthesizes
         assert reg.stats.misses == 3
         assert reg.stats.evictions == 2
 
@@ -185,12 +188,14 @@ class TestRegistry:
         topo = torus2d(4, 4)
         rows = torus_rows(4, 4)
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg1 = SynthesisEngine(topo, registry=reg1).all_gather(rows[0])
+        alg1 = SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         assert list(tmp_path.glob("*.npz"))
         assert reg1.stats.bytes_stored > 0
         # fresh registry, same dir: served from disk, no synthesis
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg2 = SynthesisEngine(topo, registry=reg2).all_gather(rows[1])
+        alg2 = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", rows[1]))
         alg2.validate()
         assert reg2.stats.disk_hits == 1 and reg2.stats.misses == 0
         assert reg2.stats.bytes_loaded > 0
@@ -202,9 +207,11 @@ class TestRegistry:
         topo = torus2d(4, 4)
         rows = torus_rows(4, 4)
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg1 = SynthesisEngine(topo, registry=reg1).all_gather(rows[0])
+        alg1 = SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg2 = SynthesisEngine(topo, registry=reg2).all_gather(rows[0])
+        alg2 = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         assert list(alg2.transfers) == list(alg1.transfers)
         assert alg2.conditions == alg1.conditions
         assert alg2.phase_spans == alg1.phase_spans
@@ -217,29 +224,33 @@ class TestRegistry:
         topo = torus2d(4, 4)
         rows = torus_rows(4, 4)
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        SynthesisEngine(topo, registry=reg1).all_gather(rows[0])
+        SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         (entry,) = tmp_path.glob("*.npz")
         NPZ_CORRUPTIONS[corrupt](entry)
 
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg = SynthesisEngine(topo, registry=reg2).all_gather(rows[0])
+        alg = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         alg.validate()
         assert reg2.stats.disk_hits == 0 and reg2.stats.misses == 1
         # the bad entry was replaced by the fresh plan
         reg3 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        SynthesisEngine(topo, registry=reg3).all_gather(rows[0])
+        SynthesisEngine(topo, registry=reg3).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         assert reg3.stats.disk_hits == 1
 
     def test_truncated_disk_entry_resynthesized(self, tmp_path):
         """Half-written file from a killed process: same contract."""
         topo = torus2d(4, 4)
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        SynthesisEngine(topo, registry=reg1).all_gather(torus_rows(4, 4)[0])
+        SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", torus_rows(4, 4)[0]))
         (entry,) = tmp_path.glob("*.npz")
         entry.write_bytes(entry.read_bytes()[: len(entry.read_bytes()) // 2])
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg = SynthesisEngine(topo, registry=reg2).all_gather(
-            torus_rows(4, 4)[1])
+        alg = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", torus_rows(4, 4)[1]))
         alg.validate()
         assert reg2.stats.misses == 1
 
@@ -250,14 +261,16 @@ class TestRegistry:
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
         # rows[0] is its own canonical form, so the returned algorithm is
         # exactly what a legacy registry would have serialized
-        alg = SynthesisEngine(topo, registry=reg1).all_gather(rows[0])
+        alg = SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         (npz,) = tmp_path.glob("*.npz")
         npz.with_suffix(".json").write_text(to_msccl_json(alg),
                                             encoding="utf-8")
         npz.unlink()
 
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg2 = SynthesisEngine(topo, registry=reg2).all_gather(rows[1])
+        alg2 = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", rows[1]))
         alg2.validate()
         assert reg2.stats.disk_hits == 1 and reg2.stats.misses == 0
         assert alg2.makespan == alg.makespan
@@ -266,7 +279,8 @@ class TestRegistry:
         assert not list(tmp_path.glob("*.json"))
         # and the migrated entry serves the next registry
         reg3 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        SynthesisEngine(topo, registry=reg3).all_gather(rows[0])
+        SynthesisEngine(topo, registry=reg3).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         assert reg3.stats.disk_hits == 1 and reg3.stats.misses == 0
 
     def test_corrupt_legacy_json_dropped(self, tmp_path):
@@ -274,13 +288,15 @@ class TestRegistry:
         topo = torus2d(4, 4)
         rows = torus_rows(4, 4)
         reg1 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        SynthesisEngine(topo, registry=reg1).all_gather(rows[0])
+        SynthesisEngine(topo, registry=reg1).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         (npz,) = tmp_path.glob("*.npz")
         npz.with_suffix(".json").write_text("{ not json", encoding="utf-8")
         npz.unlink()
 
         reg2 = AlgorithmRegistry(cache_dir=str(tmp_path))
-        alg = SynthesisEngine(topo, registry=reg2).all_gather(rows[0])
+        alg = SynthesisEngine(topo, registry=reg2).collective(CollectiveRequest(
+            "all_gather", rows[0]))
         alg.validate()
         assert reg2.stats.misses == 1
         assert not list(tmp_path.glob("*.json"))
@@ -288,7 +304,8 @@ class TestRegistry:
     def test_relabel_preserves_validity_on_reduce(self):
         topo = torus2d(4, 4)
         eng = SynthesisEngine(topo)
-        alg = eng.reduce_scatter(torus_rows(4, 4)[0])
+        alg = eng.collective(CollectiveRequest(
+            "reduce_scatter", torus_rows(4, 4)[0]))
         shift = topo.automorphism_generators[0]  # row translation
         relabeled = relabel_algorithm(alg, shift)
         relabeled.validate()
@@ -302,8 +319,10 @@ class TestTranslateRoundtrip:
     def test_msccl_json_roundtrip(self):
         topo = torus2d(3, 3)
         eng = SynthesisEngine(topo)
-        for alg in (eng.all_gather(list(range(9))),
-                    eng.all_reduce(list(range(9)))):
+        for alg in (eng.collective(CollectiveRequest(
+            "all_gather", list(range(9)))),
+                    eng.collective(CollectiveRequest(
+                        "all_reduce", list(range(9))))):
             rt = from_msccl_json(to_msccl_json(alg), topo)
             rt.validate()
             assert rt.makespan == alg.makespan
@@ -319,9 +338,8 @@ class TestJointSynthesis:
     def test_duplicate_chunk_rejection(self):
         topo = mesh2d(2, 2)
         with pytest.raises(ValueError, match="duplicate chunk"):
-            synthesize_joint(
-                topo, [("a", all_gather([0, 1])), ("b", all_gather([2, 3]))]
-            )
+            SynthesisEngine(topo).synthesize_joint(
+                [("a", all_gather([0, 1])), ("b", all_gather([2, 3]))])
 
     def test_multi_group_congestion_freedom(self):
         """Two process groups synthesized jointly never overlap on a link —
@@ -330,10 +348,8 @@ class TestJointSynthesis:
         ids = ChunkIds()
         g1 = [0, 1, 2, 3]
         g2 = [12, 13, 14, 15]
-        alg = synthesize_joint(
-            topo,
-            [("pg0", all_gather(g1, ids=ids)), ("pg1", all_to_all(g2, ids=ids))],
-        )
+        alg = SynthesisEngine(topo).synthesize_joint(
+            [("pg0", all_gather(g1, ids=ids)), ("pg1", all_to_all(g2, ids=ids))])
         alg.validate()
         by_link: dict = {}
         for t in alg.transfers:
@@ -351,8 +367,10 @@ class TestJointSynthesis:
         reg = AlgorithmRegistry()
         eng = SynthesisEngine(topo, registry=reg)
         ids = ChunkIds()
-        a = eng.all_gather([0, 1, 2, 3], ids=ids)
-        b = eng.all_gather([8, 9, 10, 11], ids=ids)  # registry hit, remapped
+        a = eng.collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]), ids=ids)
+        b = eng.collective(CollectiveRequest(
+            "all_gather", [8, 9, 10, 11]), ids=ids)  # registry hit, remapped
         chunks = [c.chunk for c in a.conditions] + [c.chunk for c in b.conditions]
         assert len(set(chunks)) == 8
         assert reg.stats.hits == 1
@@ -366,7 +384,8 @@ class TestCommsPlanCache:
 
         clear_plan_cache()
         topo = ring(4, bidirectional=True)
-        alg = synthesize_all_gather(topo, list(range(4)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         prog = to_ppermute_program(alg)
         before = counters()
         p1 = plan_buffers_cached(prog, "fp-1")
@@ -378,15 +397,11 @@ class TestCommsPlanCache:
         clear_plan_cache()
 
     def test_synthesize_program_reuses_plan(self):
-        from repro.comms.primitives import (
-            _PROGRAM_CACHE,
-            CollectiveSpec,
-            synthesize_program,
-        )
+        from repro.comms.primitives import _PROGRAM_CACHE, synthesize_program
         from repro.tracing import counters
 
         topo = ring(4, bidirectional=True)
-        spec = CollectiveSpec("all_gather", (0, 1, 2, 3))
+        spec = CollectiveRequest("all_gather", (0, 1, 2, 3))
         reg = AlgorithmRegistry()
         prog1, plan1 = synthesize_program(topo, spec, registry=reg)
         before = counters()
@@ -422,11 +437,11 @@ class TestCacheHygiene:
         import gc
         import weakref
 
-        from repro.comms.primitives import CollectiveSpec, synthesize_program
+        from repro.comms.primitives import synthesize_program
 
         topo = ring(4, bidirectional=True)
         reg = AlgorithmRegistry()
-        synthesize_program(topo, CollectiveSpec("all_gather", (0, 1, 2, 3)),
+        synthesize_program(topo, CollectiveRequest("all_gather", (0, 1, 2, 3)),
                            registry=reg)
         ref = weakref.ref(topo)
         del topo

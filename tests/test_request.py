@@ -1,4 +1,4 @@
-"""The CollectiveRequest API redesign and the unified PCCLError surface.
+"""The CollectiveRequest API and the unified PCCLError surface.
 
 Three contracts:
 
@@ -6,37 +6,35 @@ Three contracts:
    that rejects malformed descriptions at construction (unknown kind,
    non-positive bytes, root on a non-reduce, ...), so every downstream
    layer can trust a request it receives.
-2. **Equivalence.** The legacy per-call kwargs and the request form
-   produce bit-identical schedules through the *same* registry entries —
-   the redesign changes the call surface, not the plans — and explicitly
-   passing a legacy tuning kwarg warns :class:`PCCLDeprecationWarning`
-   (escalated to an error for repro-internal call sites by pyproject).
+2. **One plan per description.** Every way of naming a collective (a
+   request to the engine, a kind name or a request to
+   ``MeshCollectivePlanner``) reaches the same registry entry, program and
+   buffer plan, and the registry's params tuples stay pinned so on-disk
+   cache entries keep serving.
 3. **Error surface.** Every domain error derives from :class:`PCCLError`,
    and the silent flat-fallback rules hold: ``HierarchyError`` is advisory
    (the auto route may fall back flat), ``SketchInfeasibleError`` and
    ``FabricDegradedError`` are hard (no fallback may swallow them).
 """
 
-import warnings
-
 import numpy as np
 import pytest
 
+from repro.comms.primitives import synthesize_program
 from repro.core import (
     AlgorithmRegistry,
+    ChunkIds,
     CollectiveRequest,
     FabricDegradedError,
     HierarchyError,
-    PCCLDeprecationWarning,
     PCCLError,
     SketchInfeasibleError,
     SynthesisEngine,
-    synthesize_all_gather,
-    synthesize_all_to_all,
 )
-from repro.topology import multi_pod, ring, torus2d
-
-LEGACY_OK = "ignore::repro.core.request.PCCLDeprecationWarning"
+from repro.core import conditions as cnd
+from repro.core.engine import time_reversed
+from repro.core.translate import to_ppermute_program
+from repro.topology import mesh2d, multi_pod, ring, torus2d
 
 
 def _same_schedule(a, b) -> bool:
@@ -108,71 +106,44 @@ class TestRequestValidation:
 
 
 class TestLegacyShimEquivalence:
-    """Old kwargs and new requests must be two spellings of one plan."""
-
-    @pytest.mark.filterwarnings(LEGACY_OK)
-    def test_all_gather_same_registry_entry_and_columns(self):
-        reg = AlgorithmRegistry()
-        eng = SynthesisEngine(torus2d(4, 4), registry=reg)
-        legacy = eng.all_gather(list(range(4)), bytes=2.0, chunks_per_npu=2)
-        misses = reg.stats.misses
-        new = eng.collective(CollectiveRequest(
-            "all_gather", group=tuple(range(4)), bytes=2.0, chunks=2))
-        assert reg.stats.misses == misses, "request form missed the cache"
-        assert reg.stats.hits >= 1
-        assert _same_schedule(legacy, new)
-
-    @pytest.mark.filterwarnings(LEGACY_OK)
-    def test_pipelined_all_reduce_equivalent(self):
-        reg = AlgorithmRegistry()
-        eng = SynthesisEngine(torus2d(4, 4), registry=reg)
-        legacy = eng.all_reduce(list(range(4)), pipelined=True)
-        misses = reg.stats.misses
-        new = eng.collective(CollectiveRequest(
-            "all_reduce", group=tuple(range(4)), pipelined=True))
-        assert reg.stats.misses == misses
-        assert _same_schedule(legacy, new)
+    """The request forms that replaced the named shims and module wrappers
+    ask for the plans those gave."""
 
     def test_reduce_request_carries_root(self):
-        reg = AlgorithmRegistry()
-        eng = SynthesisEngine(torus2d(4, 4), registry=reg)
-        legacy = eng.reduce(list(range(4)), 2)
-        new = eng.collective(CollectiveRequest(
-            "reduce", group=tuple(range(4)), root=2))
-        assert _same_schedule(legacy, new)
-
-    def test_explicit_legacy_kwarg_warns(self):
-        eng = SynthesisEngine(torus2d(4, 4), registry=AlgorithmRegistry())
-        with pytest.warns(PCCLDeprecationWarning, match="deprecated"):
-            eng.all_gather(list(range(4)), bytes=2.0)
-
-    def test_bare_named_call_stays_silent_sugar(self):
-        eng = SynthesisEngine(torus2d(4, 4), registry=AlgorithmRegistry())
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            eng.all_gather(list(range(4))).validate()
-
-    def test_module_wrappers_are_warning_free(self):
         topo = torus2d(4, 4)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            synthesize_all_gather(topo, list(range(4)),
-                                  chunks_per_npu=2).validate()
-            synthesize_all_to_all(topo, list(range(4)),
-                                  chunks_per_pair=2,
-                                  hierarchy="never").validate()
+        got = SynthesisEngine(topo).collective(CollectiveRequest(
+            "reduce", group=tuple(range(4)), root=2))
+        got.validate()
+        # the reduce synthesized by hand: a broadcast from the root on the
+        # reversed fabric, time-reversed onto the forward one
+        rconds = cnd.reduce(list(range(4)), 2, ids=ChunkIds(0))
+        ref = SynthesisEngine(topo)
+        bcast = ref.synthesize(cnd.gather_view(rconds, tag="rev_bcast"),
+                               name="pccl_reduce",
+                               topology=ref.reversed_topology())
+        want = time_reversed(topo, bcast, rconds)
+        assert _same_schedule(got, want)
+        assert {d for c in got.conditions for d in c.dests} == {2}
 
-    def test_request_of_wrong_kind_rejected_by_named_method(self):
-        eng = SynthesisEngine(torus2d(4, 4), registry=AlgorithmRegistry())
-        req = CollectiveRequest("all_to_all", group=tuple(range(4)))
-        with pytest.raises(ValueError, match="all_to_all"):
-            eng.all_gather(req)
-
-    def test_request_plus_kwargs_rejected(self):
-        eng = SynthesisEngine(torus2d(4, 4), registry=AlgorithmRegistry())
-        req = CollectiveRequest("all_gather", group=tuple(range(4)))
-        with pytest.raises(TypeError, match="CollectiveRequest"):
-            eng.all_gather(req, bytes=2.0)
+    @pytest.mark.parametrize("req, route, params", [
+        (CollectiveRequest("all_gather", (0, 1, 2, 3), bytes=2.0, chunks=2),
+         (False, False, None), (2.0, 2, (False, False, None))),
+        (CollectiveRequest("all_to_all", (0, 1, 2, 3), chunks=3),
+         (False, False, None), (1.0, 3, (False, False, None))),
+        (CollectiveRequest("reduce_scatter", (4, 5, 6, 7), bytes=0.5),
+         (True, False, "pf", "te", None),
+         (0.5, 1, (True, False, "pf", "te", None))),
+        (CollectiveRequest("all_reduce", (0, 1, 2, 3), bytes=6.25,
+                           pipelined=True),
+         (False, False, None), (6.25, True, (False, False, None))),
+        (CollectiveRequest("reduce", (3, 1, 2), root=2, bytes=4.0),
+         None, (4.0, 2)),
+    ], ids=["all_gather", "all_to_all", "reduce_scatter", "all_reduce",
+            "reduce"])
+    def test_registry_params_are_pinned(self, req, route, params):
+        """The registry keys (and the on-disk cache entries written under
+        them) hold these tuples; a change here orphans every cached plan."""
+        assert req.registry_params(route) == params
 
     def test_empty_group_request_rejected_at_synthesis(self):
         eng = SynthesisEngine(torus2d(4, 4), registry=AlgorithmRegistry())
@@ -190,15 +161,56 @@ class TestPlannerRequestPath:
                                      registry=AlgorithmRegistry())
 
     def test_request_and_legacy_agree(self, planner):
-        via_name = planner.algorithm("all_gather", "model", 0)
+        """The string form of ``algorithm`` is the request form at
+        ``nbytes`` with every other field at its default."""
+        via_name = planner.algorithm("all_gather", "model", 0, nbytes=2.0)
         via_req = planner.algorithm(
-            CollectiveRequest("all_gather"), "model", 0)
+            CollectiveRequest("all_gather", bytes=2.0), "model", 0)
         assert _same_schedule(via_name, via_req)
 
     def test_request_with_tuning_kwargs_rejected(self, planner):
-        with pytest.raises(TypeError, match="CollectiveRequest"):
+        with pytest.raises(TypeError, match="hierarchy"):
             planner.algorithm(CollectiveRequest("all_gather"), "model", 0,
                               hierarchy="never")
+        with pytest.raises(TypeError, match="chunks_per_npu"):
+            planner.algorithm("all_gather", "model", 0, chunks_per_npu=2)
+
+
+class TestProgramRequestPath:
+    """``MeshCollectivePlanner.program`` and ``synthesize_program`` on a
+    request are one way in: the same program and the same cached plan."""
+
+    @pytest.mark.parametrize("kind, nbytes", [
+        ("all_gather", 1.4140625),
+        ("all_to_all", 0.5),
+        ("reduce_scatter", 2.0),
+        ("all_reduce", 6.25),
+    ])
+    def test_planner_program_is_the_request_program(self, kind, nbytes):
+        from repro.launch.sharding import MeshCollectivePlanner
+
+        planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
+                                        registry=AlgorithmRegistry())
+        prog, plan = planner.program(kind, "x", 0, nbytes=nbytes)
+        req = CollectiveRequest(kind, (0, 1, 2, 3), bytes=nbytes,
+                                pipelined=kind == "all_reduce")
+        prog2, plan2 = synthesize_program(planner.topo, req,
+                                          registry=planner.registry)
+        # one cache entry, not two equal ones
+        assert prog2 is prog and plan2 is plan
+        fresh = SynthesisEngine(mesh2d(2, 2)).collective(req)
+        assert to_ppermute_program(fresh).digest() == prog.digest()
+
+    @pytest.mark.parametrize("bad, err, match", [
+        (CollectiveRequest("reduce", (0, 1, 2, 3), root=0), ValueError,
+         "not executable"),
+        (CollectiveRequest("all_gather"), ValueError, "explicit group"),
+        (("all_gather", (0, 1, 2, 3)), TypeError, "CollectiveRequest"),
+    ], ids=["reduce", "empty_group", "not_a_request"])
+    def test_synthesize_program_rejects(self, bad, err, match):
+        with pytest.raises(err, match=match):
+            synthesize_program(mesh2d(2, 2), bad,
+                               registry=AlgorithmRegistry())
 
 
 class TestErrorSurface:

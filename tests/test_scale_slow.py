@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from repro.core import AlgorithmRegistry
+from repro.core import AlgorithmRegistry, CollectiveRequest
 from repro.core.engine import SynthesisEngine
 from repro.topology import mesh2d, three_level
 
@@ -33,7 +33,8 @@ def test_mesh12x12_all_to_all_within_budget():
     topo = mesh2d(12, 12)
     n = 144
     t0 = time.perf_counter()
-    alg = SynthesisEngine(topo).all_to_all(list(range(n)))
+    alg = SynthesisEngine(topo).collective(CollectiveRequest(
+        "all_to_all", list(range(n))))
     synth_s = time.perf_counter() - t0
     alg.validate()
     wall_s = time.perf_counter() - t0
@@ -56,7 +57,8 @@ def test_three_level_2048_all_gather_within_budget():
     n = 2048
     reg = AlgorithmRegistry()
     t0 = time.perf_counter()
-    alg = SynthesisEngine(topo, registry=reg).all_gather(topo.npus)
+    alg = SynthesisEngine(topo, registry=reg).collective(CollectiveRequest(
+        "all_gather", topo.npus))
     synth_s = time.perf_counter() - t0
     alg.validate(mode="bulk")
     wall_s = time.perf_counter() - t0
@@ -88,7 +90,7 @@ def test_three_level_512_te_vs_rr_within_budget():
         topo = three_level(8, 8, 8, unit_links=True)
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry(),
                               gateway_strategy=strategy)
-        alg = eng.all_gather(topo.npus)
+        alg = eng.collective(CollectiveRequest("all_gather", topo.npus))
         alg.validate(mode="bulk")
         assert alg.name == "pccl_hier_all_gather"
         spans[strategy] = alg.makespan

@@ -4,7 +4,9 @@ anchored on analytically known completion times."""
 import pytest
 
 from repro.core import (
+    CollectiveRequest,
     Flow,
+    SynthesisEngine,
     collective_bandwidth,
     direct_all_gather,
     direct_all_to_all,
@@ -12,8 +14,6 @@ from repro.core import (
     ring_all_gather,
     shortest_path_links,
     simulate_flows,
-    synthesize_all_gather,
-    synthesize_all_to_all,
 )
 from repro.topology import line, mesh2d, ring, torus2d
 from repro.topology.topology import Topology
@@ -67,7 +67,8 @@ class TestSimulator:
 
     def test_busy_timeline_shape(self):
         topo = ring(4)
-        alg = synthesize_all_gather(topo, [0, 1, 2, 3])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]))
         res = replay_algorithm(alg)
         timeline = res.busy_timeline(topo.num_links, bins=10)
         assert len(timeline) == 10
@@ -104,7 +105,8 @@ class TestBaselines:
     def test_pccl_beats_direct_on_mesh(self):
         # the paper's central claim (Fig 14/16)
         topo = mesh2d(4, 4)
-        pccl = synthesize_all_to_all(topo, list(range(16)))
+        pccl = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", list(range(16))))
         direct = direct_all_to_all(topo, list(range(16)))
         assert pccl.makespan < direct.makespan
 
@@ -112,7 +114,8 @@ class TestBaselines:
         # process group = one mesh row; PCCL borrows other rows' links
         topo = mesh2d(4, 4)
         group = [0, 1, 2, 3]
-        pccl = synthesize_all_to_all(topo, group)
+        pccl = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", group))
         pccl.validate()
         direct = direct_all_to_all(topo, group)
         assert pccl.makespan <= direct.makespan
@@ -122,14 +125,16 @@ class TestBaselines:
         # PCCL must match it (both n-1 steps)
         topo = ring(6)
         base = ring_all_gather(topo, list(range(6)))
-        pccl = synthesize_all_gather(topo, list(range(6)))
+        pccl = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(6))))
         assert base.makespan == pytest.approx(pccl.makespan) == 5.0
 
     def test_ring_ag_unaware_on_torus_loses(self):
         # paper Fig 3b: topology-unaware ring underutilizes richer networks
         topo = torus2d(3, 3)
         base = ring_all_gather(topo, list(range(9)))
-        pccl = synthesize_all_gather(topo, list(range(9)))
+        pccl = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(9))))
         assert pccl.makespan < base.makespan
 
     def test_direct_ag(self):
@@ -139,7 +144,8 @@ class TestBaselines:
 
     def test_bandwidth_metric(self):
         topo = ring(4)
-        alg = synthesize_all_gather(topo, [0, 1, 2, 3])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", [0, 1, 2, 3]))
         res = replay_algorithm(alg)
         bw = collective_bandwidth(res, payload_bytes=4.0)
         assert bw == pytest.approx(4.0 / 3.0)

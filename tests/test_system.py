@@ -4,13 +4,12 @@ translate -> evaluate, on the production pod topology."""
 
 from repro.core import (
     ChunkIds,
+    CollectiveRequest,
+    SynthesisEngine,
     all_to_all,
     all_to_allv,
     direct_all_to_all,
     replay_algorithm,
-    synthesize,
-    synthesize_all_to_all,
-    synthesize_joint,
     to_msccl_json,
     to_ppermute_program,
 )
@@ -23,7 +22,8 @@ class TestEndToEnd:
         validate, translate to a ppermute program."""
         topo = tpu_v5e_pod(8, 8)
         row = list(range(8))
-        alg = synthesize_all_to_all(topo, row, bytes=1.0)
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", row, bytes=1.0))
         alg.validate()
         prog = to_ppermute_program(alg)
         assert prog.num_rounds >= 1
@@ -39,7 +39,7 @@ class TestEndToEnd:
         for r in range(4):
             row = [r * 4 + c for c in range(4)]
             groups.append((f"row{r}", all_to_all(row, ids=ids, bytes=1.0)))
-        alg = synthesize_joint(topo, groups)
+        alg = SynthesisEngine(topo).synthesize_joint(groups)
         alg.validate()
 
     def test_process_group_speedup_claim(self):
@@ -47,7 +47,8 @@ class TestEndToEnd:
         The paper reports 2.33-3.03x; we assert a sound >1.15x on 6x6."""
         topo = mesh2d(6, 6)
         group = list(range(6))  # one row
-        pccl = synthesize_all_to_all(topo, group)
+        pccl = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", group))
         pccl.validate()
         direct = direct_all_to_all(topo, group)
         speedup = direct.makespan / pccl.makespan
@@ -57,7 +58,8 @@ class TestEndToEnd:
         import json
 
         topo = mesh2d(3, 3)
-        alg = synthesize_all_to_all(topo, [0, 1, 2])
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_to_all", [0, 1, 2]))
         doc = json.loads(to_msccl_json(alg))
         assert doc["num_npus"] == 9
         ops = [o for g in doc["gpus"] for o in g["ops"]]
@@ -70,7 +72,7 @@ class TestEndToEnd:
         ep_group = [0, 1, 2, 3]
         counts = [[0, 3, 1, 1], [2, 0, 2, 1], [1, 1, 0, 3], [1, 2, 1, 0]]
         conds = all_to_allv(ep_group, counts)
-        alg = synthesize(topo, conds)
+        alg = SynthesisEngine(topo).synthesize(conds)
         alg.validate()
         replay = replay_algorithm(alg)
         assert replay.makespan == alg.makespan

@@ -28,6 +28,7 @@ import pytest
 
 from repro.core import (
     AlgorithmRegistry,
+    CollectiveRequest,
     CommSketch,
     SketchInfeasibleError,
     SynthesisEngine,
@@ -165,7 +166,8 @@ class TestSketchConstraints:
         allow = {p: [_uplinks(topo, p)[1][1]] for p in range(2)}
         sk = CommSketch(gateway_affinity=allow)
         alg = SynthesisEngine(topo, registry=AlgorithmRegistry(),
-                              sketch=sk).all_gather(topo.npus)
+                              sketch=sk).collective(CollectiveRequest(
+                                  "all_gather", topo.npus))
         alg.validate(mode="oracle")
         for p in range(2):
             used = {src for lid, src in _uplinks(topo, p)
@@ -183,7 +185,8 @@ class TestSketchConstraints:
                           and topo.nodes[l.src].type == NodeType.SWITCH)
         sk = CommSketch(exclude_links=banned)
         alg = SynthesisEngine(topo, registry=AlgorithmRegistry(),
-                              sketch=sk).all_to_all(topo.npus)
+                              sketch=sk).collective(CollectiveRequest(
+                                  "all_to_all", topo.npus))
         alg.validate(mode="oracle")
         assert not {tr.link for tr in alg.transfers} & banned
 
@@ -196,7 +199,8 @@ class TestSketchConstraints:
         assert adjacent
         sk = CommSketch(exclude_nodes=[victim])
         alg = SynthesisEngine(topo, registry=AlgorithmRegistry(),
-                              sketch=sk).all_gather(topo.npus)
+                              sketch=sk).collective(CollectiveRequest(
+                                  "all_gather", topo.npus))
         alg.validate(mode="oracle")
         assert not {tr.link for tr in alg.transfers} & adjacent
 
@@ -204,7 +208,8 @@ class TestSketchConstraints:
         topo = _unit_pod()
         sk = CommSketch(max_pod_ports={0: 1, 1: 1})
         alg = SynthesisEngine(topo, registry=AlgorithmRegistry(),
-                              sketch=sk).all_gather(topo.npus)
+                              sketch=sk).collective(CollectiveRequest(
+                                  "all_gather", topo.npus))
         alg.validate(mode="oracle")
         for p in range(2):
             used = {src for lid, src in _uplinks(topo, p)
@@ -222,14 +227,14 @@ class TestSketchConstraints:
             topo, registry=AlgorithmRegistry(),
             sketch=CommSketch(gateway_affinity={0: [non_gateway]}))
         with pytest.raises(SketchInfeasibleError):
-            getattr(eng, kind)(topo.npus)
+            eng.collective(CollectiveRequest(kind, topo.npus))
 
     def test_exclusion_starving_a_pod_is_infeasible(self):
         topo = _unit_pod()
         sk = CommSketch(exclude_nodes=[src for _, src in _uplinks(topo, 0)])
         eng = SynthesisEngine(topo, registry=AlgorithmRegistry(), sketch=sk)
         with pytest.raises(SketchInfeasibleError):
-            eng.all_gather(topo.npus)
+            eng.collective(CollectiveRequest("all_gather", topo.npus))
 
     def test_sketch_is_not_a_hierarchy_error(self):
         # HierarchyError triggers the engine's silent flat fallback; an
@@ -246,23 +251,26 @@ class TestRegistryStrategyKeys:
         topo = _unit_pod()
         reg = AlgorithmRegistry()
         SynthesisEngine(topo, registry=reg,
-                        gateway_strategy="round_robin").all_gather(topo.npus)
+                        gateway_strategy="round_robin").collective(CollectiveRequest(
+                            "all_gather", topo.npus))
         misses = reg.stats.misses
         SynthesisEngine(topo, registry=reg,
-                        gateway_strategy="te").all_gather(topo.npus)
+                        gateway_strategy="te").collective(CollectiveRequest(
+                            "all_gather", topo.npus))
         assert reg.stats.misses > misses, (
             "TE request was served the cached round-robin plan")
 
     def test_unconstrained_plan_misses_for_sketch(self):
         topo = _unit_pod()
         reg = AlgorithmRegistry()
-        SynthesisEngine(topo, registry=reg).all_gather(topo.npus)
+        SynthesisEngine(topo, registry=reg).collective(CollectiveRequest(
+            "all_gather", topo.npus))
         misses = reg.stats.misses
         gw = _uplinks(topo, 0)[0][1]
         alg = SynthesisEngine(
             topo, registry=reg,
             sketch=CommSketch(gateway_affinity={0: [gw]}),
-        ).all_gather(topo.npus)
+        ).collective(CollectiveRequest("all_gather", topo.npus))
         assert reg.stats.misses > misses, (
             "sketch-constrained request was served the unconstrained plan")
         alg.validate(mode="oracle")
@@ -274,9 +282,10 @@ class TestRegistryStrategyKeys:
         SynthesisEngine(
             topo, registry=reg,
             sketch=CommSketch(gateway_affinity={0: [gw]}),
-        ).all_gather(topo.npus)
+        ).collective(CollectiveRequest("all_gather", topo.npus))
         misses = reg.stats.misses
-        SynthesisEngine(topo, registry=reg).all_gather(topo.npus)
+        SynthesisEngine(topo, registry=reg).collective(CollectiveRequest(
+            "all_gather", topo.npus))
         assert reg.stats.misses > misses, (
             "unconstrained request was served the sketch-constrained plan")
 
@@ -284,10 +293,12 @@ class TestRegistryStrategyKeys:
         topo = _unit_pod()
         reg = AlgorithmRegistry()
         a = SynthesisEngine(topo, registry=reg,
-                            gateway_strategy="te").all_gather(topo.npus)
+                            gateway_strategy="te").collective(CollectiveRequest(
+                                "all_gather", topo.npus))
         misses = reg.stats.misses
         b = SynthesisEngine(topo, registry=reg,
-                            gateway_strategy="te").all_gather(topo.npus)
+                            gateway_strategy="te").collective(CollectiveRequest(
+                                "all_gather", topo.npus))
         assert reg.stats.misses == misses
         assert a.makespan == b.makespan
 

@@ -9,6 +9,7 @@ import pytest
 from repro.core import (
     AlgorithmRegistry,
     CollectiveAlgorithm,
+    CollectiveRequest,
     SynthesisEngine,
 )
 from repro.topology import multi_pod, ring, star_switch, torus2d
@@ -21,10 +22,11 @@ def algs():
     t2 = multi_pod(2, 2, 4, unit_links=True, dci_ports_per_pod=4)
     e2 = SynthesisEngine(t2, registry=AlgorithmRegistry())
     return [
-        eng.all_gather(list(range(9))),
-        eng.all_to_all(list(range(9))),
-        e2.all_gather(t2.npus),  # hierarchical, stitched phases
-        e2.all_to_all(t2.npus),
+        eng.collective(CollectiveRequest("all_gather", list(range(9)))),
+        eng.collective(CollectiveRequest("all_to_all", list(range(9)))),
+        e2.collective(CollectiveRequest(
+            "all_gather", t2.npus)),  # hierarchical, stitched phases
+        e2.collective(CollectiveRequest("all_to_all", t2.npus)),
     ]
 
 
@@ -112,7 +114,8 @@ class TestBulkMatchesOracle:
             broken.validate(mode="bulk")
 
     def test_wrong_link_endpoints_rejected(self):
-        alg = SynthesisEngine(ring(4)).all_gather(list(range(4)))
+        alg = SynthesisEngine(ring(4)).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         t = alg.transfers[0]
         broken = _mutate(alg, 0, dst=(t.dst + 1) % 4)
         with pytest.raises(AssertionError):
@@ -123,7 +126,8 @@ class TestBulkMatchesOracle:
     def test_release_violation_rejected(self):
         import dataclasses as dc
 
-        alg = SynthesisEngine(ring(4)).all_gather(list(range(4)))
+        alg = SynthesisEngine(ring(4)).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         conds = [dc.replace(c, release=5.0) for c in alg.conditions]
         broken = CollectiveAlgorithm(alg.topology, conds,
                                      list(alg.transfers), name=alg.name)
@@ -134,7 +138,8 @@ class TestBulkMatchesOracle:
 
     def test_bulk_refuses_constrained_switches(self):
         topo = star_switch(4, buffer_limit=1)
-        alg = SynthesisEngine(topo).all_gather(list(range(4)))
+        alg = SynthesisEngine(topo).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         alg.validate(mode="oracle")
         with pytest.raises(ValueError, match="bulk validation"):
             alg.validate(mode="bulk")
@@ -143,15 +148,18 @@ class TestBulkMatchesOracle:
     def test_bulk_refuses_reduce_flag_on_plain_chunk(self):
         # a reduce-flagged copy of a plain chunk is a nonstandard schedule:
         # the oracle judges it with its full replay, bulk stays out
-        alg = SynthesisEngine(ring(4)).all_gather(list(range(4)))
+        alg = SynthesisEngine(ring(4)).collective(CollectiveRequest(
+            "all_gather", list(range(4))))
         weird = _mutate(alg, 0, reduce=True)
         with pytest.raises(ValueError, match="bulk validation"):
             weird.validate(mode="bulk")
 
     def test_bulk_validates_reductions(self):
         # reductions in the in-forest normal form now take the bulk path
-        for alg in (SynthesisEngine(ring(4)).all_reduce(list(range(4))),
-                    SynthesisEngine(ring(4)).reduce_scatter(list(range(4)))):
+        for alg in (SynthesisEngine(ring(4)).collective(CollectiveRequest(
+            "all_reduce", list(range(4)))),
+                    SynthesisEngine(ring(4)).collective(CollectiveRequest(
+                        "reduce_scatter", list(range(4))))):
             alg.validate(mode="oracle")
             alg.validate(mode="bulk")
 
@@ -184,10 +192,12 @@ class TestBulkReductionDifferential:
         t2 = multi_pod(2, 2, 4, unit_links=True, dci_ports_per_pod=4)
         e2 = SynthesisEngine(t2, registry=AlgorithmRegistry())
         return [
-            eng.reduce_scatter(list(range(4))),
-            eng.all_reduce(list(range(4))),
-            e2.reduce_scatter(t2.npus),  # hierarchical, time-reversed phases
-            e2.all_reduce(t2.npus),
+            eng.collective(CollectiveRequest(
+                "reduce_scatter", list(range(4)))),
+            eng.collective(CollectiveRequest("all_reduce", list(range(4)))),
+            e2.collective(CollectiveRequest(
+                "reduce_scatter", t2.npus)),  # hierarchical, time-reversed phases
+            e2.collective(CollectiveRequest("all_reduce", t2.npus)),
         ]
 
     @staticmethod
