@@ -91,7 +91,8 @@ def build_dp_step(lm, lr, mesh, dp: int, collectives: str):
         if collectives == "pccl":
             from repro.comms import pccl_all_reduce
 
-            pad = (-vec.size) % dp
+            # the program splits the vector into one or two chunks a member
+            pad = (-vec.size) % len(program[0].chunk_holders)
             if pad:
                 vec = jnp.concatenate([vec, jnp.zeros((pad,), vec.dtype)])
             vec = pccl_all_reduce(vec, "data", topo, req, program=program)

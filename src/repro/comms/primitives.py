@@ -284,28 +284,30 @@ def pccl_all_reduce(
     device_of_npu: dict[int, int] | None = None,
     program: tuple[PpermuteProgram, BufferPlan] | None = None,
 ) -> jax.Array:
-    """All-reduce x (same shape everywhere) over the group. x is split into
-    g shard-chunks along axis 0 (must divide); composition RS∘AG per §4.5.
-    Devices outside the group return zeros."""
+    """All-reduce x (same shape everywhere) over the group. x is split along
+    axis 0 (must divide) into the program's chunks, one or two per member;
+    composition RS∘AG per §4.5. Devices outside the group return zeros."""
     prog, plan, devices, member = _resolve(
         topo, spec, device_of_npu, program, "all_reduce")
-    g = len(devices)
+    # piece i of x is chunk i in id order: member by member in group order,
+    # a split shard's halves next to each other (the engine's numbering)
     chunks = sorted(prog.chunk_holders)
-    assert len(chunks) == g, "all_reduce uses one shard-chunk per member"
-    # chunk order follows group order by construction (the engine's
-    # all-reduce is a reduce-scatter that iterates the group)
-    init_slot = np.full((prog.num_devices, g), plan.num_slots, np.int32)
+    n = len(chunks)
+    if x.shape[0] % n:
+        raise ValueError(f"all_reduce of {x.shape[0]} rows does not split "
+                         f"into the program's {n} chunks")
+    init_slot = np.full((prog.num_devices, n), plan.num_slots, np.int32)
     for ci, c in enumerate(chunks):
         for dev in devices:
             got = plan.slot_of.get((dev, c))
             if got is not None:
                 init_slot[dev, ci] = got
-    rows = slot_rows((x.shape[0] // g, *x.shape[1:]), x.dtype)
+    rows = slot_rows((x.shape[0] // n, *x.shape[1:]), x.dtype)
     with jax.named_scope("pccl.place"):
-        xs = jnp.reshape(x, (g, *rows))
+        xs = jnp.reshape(x, (n, *rows))
         idx = lax.axis_index(axis_name)
         buf = jnp.zeros((plan.buffer_slots, *rows), x.dtype)
-        for ci in range(g):
+        for ci in range(n):
             buf = lax.dynamic_update_index_in_dim(
                 buf, xs[ci], jnp.asarray(init_slot[:, ci])[idx], axis=0
             )

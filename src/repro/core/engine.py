@@ -984,18 +984,10 @@ class SynthesisEngine:
             return self._reduce_scatter_impl(g, bytes=req.bytes,
                                              chunks_per_npu=req.chunks)
         return self._all_reduce_impl(g, bytes=req.bytes,
-                                     pipelined=req.pipelined)
+                                     pipelined=req.pipelined,
+                                     chunks=req.chunks)
 
     # -- reduction internals (paper §4.5, Fig. 8) ---------------------------
-
-    def _reverse_algorithm(
-        self,
-        alg: CollectiveAlgorithm,
-        reduce_conds: list[ReduceCondition],
-    ) -> CollectiveAlgorithm:
-        """See :func:`time_reversed` — engine-local wrapper binding the
-        forward fabric."""
-        return time_reversed(self.topology, alg, reduce_conds)
 
     def _reduce_impl(
         self, group: list[int], root: int, *, bytes: float = 1.0,
@@ -1004,52 +996,92 @@ class SynthesisEngine:
         bcast = cnd.gather_view(rconds, tag="rev_bcast")
         alg = self.synthesize(bcast, name="pccl_reduce",
                               topology=self.reversed_topology())
-        return self._reverse_algorithm(alg, rconds)
+        return time_reversed(self.topology, alg, rconds)
 
     def _reduce_scatter_impl(
         self, group: list[int], *, bytes: float = 1.0, chunks_per_npu: int = 1,
+        topology: Topology | None = None,
     ) -> CollectiveAlgorithm:
+        """Reduce-Scatter by time reversal, on ``topology`` (default: the
+        engine's fabric; a sub-topology keeps its own link ids)."""
+        topo = topology or self.topology
         rconds = cnd.reduce_scatter(group, ids=ChunkIds(0), bytes=bytes,
                                     chunks_per_npu=chunks_per_npu)
         ag = cnd.gather_view(rconds, tag="rev_ag")
         alg = self.synthesize(ag, name="pccl_reduce_scatter",
-                              topology=self.reversed_topology())
-        return self._reverse_algorithm(alg, rconds)
+                              topology=topo.reversed())
+        return time_reversed(topo, alg, rconds)
 
     def _all_reduce_impl(
         self, group: list[int], *, bytes: float = 1.0, pipelined: bool = False,
+        chunks: int = 1,
     ) -> CollectiveAlgorithm:
         """All-Reduce = Reduce-Scatter then All-Gather (paper §4.5), composed
-        as a two-phase :class:`PhasePlan`. Each NPU in the group owns one
-        shard-chunk. With ``pipelined=True`` (beyond-paper), each chunk's
-        All-Gather is released at that chunk's Reduce-Scatter completion
-        instead of the global makespan; ``preload_from`` keeps the
-        overlapping phases congestion-free on the shared links."""
-        rs = self._reduce_scatter_impl(group, bytes=bytes)
-        owner = {c.chunk: next(iter(c.dests)) for c in rs.conditions}
-        ag_conds = [
-            Condition(c.chunk, owner[c.chunk], frozenset(group), bytes=bytes,
-                      tag="allreduce_ag")
-            for c in rs.conditions
-        ]
-        ar_conds = [
-            ReduceCondition(c.chunk, frozenset(group), frozenset(group),
-                            bytes=bytes)
-            for c in rs.conditions
-        ]
-        # pipelined: each chunk's gather releases at its own reduce
-        # completion — the per-chunk floor vector derived from the RS
-        # phase's columns; barrier mode floors the whole phase at RS end
-        plan = PhasePlan(
-            phases=[
-                PhaseSpec("reduce_scatter", algorithm=rs),
-                PhaseSpec("all_gather", conds=ag_conds,
-                          preload_from=("reduce_scatter",),
-                          floors_from=(("reduce_scatter",) if pipelined
-                                       else ()),
-                          after=(() if pipelined else ("reduce_scatter",))),
-            ],
-            conditions=ar_conds,
-            name="pccl_all_reduce",
-        )
+        as a :class:`PhasePlan`. Each NPU in the group owns ``chunks``
+        shard-chunks of ``bytes``. With ``pipelined=True`` (beyond-paper),
+        each chunk's All-Gather is released at that chunk's Reduce-Scatter
+        completion instead of the global makespan; ``preload_from`` keeps
+        the overlapping phases congestion-free on the shared links.
+
+        ``chunks=2`` splits each shard in two and runs the RS∘AG of every
+        half 0 on one strong orientation of the fabric
+        (:meth:`Topology.opposite_orientations`) and of every half 1 on its
+        reverse: two link-disjoint schedules on one clock, so a step can
+        drive every link where whole shards leave some idle. Member i's
+        halves are chunks 2i and 2i + 1. Where the fabric has no strong
+        orientation (a bridge), or the split schedule is no shorter than
+        one chunk of ``2 * bytes`` per member, that one-chunk schedule is
+        returned instead."""
+        if chunks == 1:
+            return self._all_reduce_families(group, [None], bytes, pipelined)
+        whole = self._all_reduce_families(group, [None], 2 * bytes, pipelined)
+        views = self.topology.opposite_orientations(group[0])
+        if views is None:
+            return whole
+        split = self._all_reduce_families(group, list(views), bytes,
+                                          pipelined)
+        return split if split.makespan < whole.makespan else whole
+
+    def _all_reduce_families(self, group: list[int], views: list,
+                             bytes: float,
+                             pipelined: bool) -> CollectiveAlgorithm:
+        """One RS∘AG per view (None: the engine's fabric), each on one
+        chunk of every member's shard: view h's chunk of member i is chunk
+        ``len(views) * i + h``. One family is the plain all-reduce."""
+        k = len(views)
+        phases: list[PhaseSpec] = []
+        ar_conds: list[ReduceCondition] = []
+        for h, view in enumerate(views):
+            topo = self.topology if view is None else view.topology
+            rs = self._reduce_scatter_impl(group, bytes=bytes, topology=topo)
+            cm = ({c.chunk: k * i + h for i, c in enumerate(rs.conditions)}
+                  if k > 1 else None)
+            lift = {} if view is None else dict(
+                topology=topo, node_map=view.nodes, link_map=view.links,
+                chunk_map=cm)
+            rs_name, ag_name = (("reduce_scatter", "all_gather") if k == 1
+                                else (f"reduce_scatter{h}", f"all_gather{h}"))
+            owner = {c.chunk: next(iter(c.dests)) for c in rs.conditions}
+            ag_conds = [
+                Condition(c.chunk, owner[c.chunk], frozenset(group),
+                          bytes=bytes, tag="allreduce_ag")
+                for c in rs.conditions
+            ]
+            ar_conds += [
+                ReduceCondition((cm or {}).get(c.chunk, c.chunk),
+                                frozenset(group), frozenset(group),
+                                bytes=bytes)
+                for c in rs.conditions
+            ]
+            # pipelined: each chunk's gather releases at its own reduce
+            # completion — the per-chunk floor vector derived from the RS
+            # phase's columns; barrier mode floors the whole phase at RS end
+            phases += [
+                PhaseSpec(rs_name, algorithm=rs, **lift),
+                PhaseSpec(ag_name, conds=ag_conds, preload_from=(rs_name,),
+                          floors_from=(rs_name,) if pipelined else (),
+                          after=() if pipelined else (rs_name,), **lift),
+            ]
+        plan = PhasePlan(phases=phases, conditions=sorted(
+            ar_conds, key=lambda c: c.chunk), name="pccl_all_reduce")
         return self.synthesize_plan(plan)

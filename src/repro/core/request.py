@@ -17,8 +17,9 @@ every layer below can trust it:
   it is identity-bearing mutable state, not a description of the
   collective, so it never belongs in the frozen request.
 
-``chunks`` is the per-NPU chunk count for the gather/reduce-scatter family
-and the per-pair count for all_to_all.
+``chunks`` is the per-NPU chunk count for the gather/reduce-scatter family,
+the per-pair count for all_to_all, and for all_reduce the pieces (1 or 2)
+each member's shard is split into, every piece of ``bytes``.
 """
 
 from __future__ import annotations
@@ -50,7 +51,7 @@ class CollectiveRequest:
     kind: str
     group: tuple = ()
     bytes: float = 1.0
-    chunks: int = 1  # per-NPU (gather family) / per-pair (all_to_all)
+    chunks: int = 1  # per-NPU (gathers) / per-pair (all_to_all) / per shard
     root: int | None = None  # reduce only
     hierarchy: str = "auto"
     pipelined: bool = False  # all_reduce flat route only
@@ -81,6 +82,10 @@ class CollectiveRequest:
                     f"root {self.root} not in group")
         elif self.root is not None:
             raise ValueError(f"root= only applies to reduce, not {self.kind}")
+        if self.kind == "all_reduce" and self.chunks > 2:
+            raise ValueError(
+                f"chunks={self.chunks}: an all_reduce splits a shard in 1 "
+                f"or 2 chunks")
         if self.pipelined and self.kind != "all_reduce":
             raise ValueError(
                 f"pipelined= only applies to all_reduce, not {self.kind}")
@@ -101,7 +106,9 @@ class CollectiveRequest:
         if self.kind == "reduce":
             return (self.bytes, self.group.index(self.root))
         if self.kind == "all_reduce":
-            return (self.bytes, self.pipelined, route)
+            params = (self.bytes, self.pipelined, route)
+            # a split shard keys apart; one chunk keeps the cached layout
+            return params if self.chunks == 1 else (*params, self.chunks)
         # all_gather / all_to_all / reduce_scatter
         return (self.bytes, self.chunks, route)
 

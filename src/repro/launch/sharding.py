@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.tracing import count
 
 
 def pad_heads(cfg: ModelConfig, tp: int) -> ModelConfig:
@@ -217,6 +218,20 @@ class ShardingPolicy:
 # Mesh-axis collectives through the algorithm registry
 # ---------------------------------------------------------------------------
 
+# An all-reduce asked for by name splits each member's shard in two
+# (``CollectiveRequest.chunks=2``: halves around opposite orientations of
+# the fabric) when its chunk is larger than this, in MiB. On the v5e 2x2 a
+# split turns 4 waves of one S/4 chunk into 6 waves of S/8, so the busiest
+# link carries 0.75 S instead of S: it saves one chunk's transfer at one
+# link's ~48 GB/s (137 us a 6.25 MiB chunk). It costs 4 more rounds of
+# copies at ~0.82 us and 2 more waves at ~0.3 us of fixed permute cost,
+# ~3.9 us (v5e 2x2 readings: a 96 KiB round's copies, and a permute's
+# time above its bytes). Break-even: 3.9 us * 48 GB/s = 0.18 MiB. A
+# gradient bucket's chunk (MiBs) splits; a decode activation's (0.09 MiB)
+# does not.
+SPLIT_ALL_REDUCE_MIB = 0.18
+
+
 class MeshCollectivePlanner:
     """Routes per-mesh-axis process-group collectives through the shared
     :class:`repro.core.registry.AlgorithmRegistry`.
@@ -373,7 +388,11 @@ class MeshCollectivePlanner:
         :class:`repro.core.request.CollectiveRequest` (group filled in from
         the axis), mirroring :meth:`algorithm` — requests execute any engine
         route (hierarchy, TE gateways, sketches, pipelining). A name is
-        asked for at ``nbytes``, and an all-reduce pipelined."""
+        asked for at ``nbytes``, one member's chunk; an all-reduce is
+        pipelined, and its shard split in two halves of ``nbytes / 2``
+        when ``nbytes`` exceeds ``SPLIT_ALL_REDUCE_MIB``. Counts
+        ``all_reduce.split_shards`` once per call whose program holds two
+        chunks a member."""
         from repro.comms.primitives import synthesize_program
         from repro.core.request import CollectiveRequest
 
@@ -381,9 +400,15 @@ class MeshCollectivePlanner:
         if isinstance(kind, CollectiveRequest):
             req = kind.with_group(group)
         else:
-            req = CollectiveRequest(kind, group, bytes=nbytes,
+            split = kind == "all_reduce" and nbytes > SPLIT_ALL_REDUCE_MIB
+            req = CollectiveRequest(kind, group, bytes=nbytes / (1 + split),
+                                    chunks=1 + split,
                                     pipelined=kind == "all_reduce")
-        return synthesize_program(
+        prog, plan = synthesize_program(
             self.topo, req, registry=self.registry,
             device_of_npu=device_of_npu,
         )
+        if req.kind == "all_reduce" and len(prog.chunk_holders) == 2 * len(
+                group):
+            count("all_reduce.split_shards")
+        return prog, plan

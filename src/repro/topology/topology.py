@@ -741,6 +741,63 @@ class Topology:
         rev._rev_cache = self
         return rev
 
+    def opposite_orientations(
+            self, root: int) -> tuple[TopologyView, TopologyView] | None:
+        """A strong orientation of the fabric's undirected link graph and
+        its reverse, as two link-disjoint views over every node: the first
+        keeps one link of each opposite pair (u -> v, v -> u), the second
+        the other. A chunk may go around either view between any two nodes
+        of ``root``'s component, and the two together use every paired link
+        once.
+
+        The orientation is a depth-first one from ``root``: a tree edge
+        points away from the root, every other edge back to the ancestor it
+        closes a cycle with. By Robbins' theorem a graph has a strong
+        orientation iff it has no bridge, and the depth-first one is strong
+        exactly then; so this returns None when some node of the component
+        cannot reach ``root``, or be reached from it, in the first view (a
+        bridge, or a link with no opposite partner on the way). Memoized
+        per root."""
+        views = self._views()
+        key = ("orient", root)
+        if key in views:
+            return views[key]
+        partner: dict[tuple[int, int], list[int]] = {}
+        for l in self.links:
+            partner.setdefault((l.src, l.dst), []).append(l.id)
+        used: set[int] = set()
+        forward: list[int] = []
+        backward: list[int] = []
+        seen = {root}
+        stack = [iter(self._out[root])]
+        while stack:
+            for l in stack[-1]:
+                if l.id in used:
+                    continue
+                back = next((b for b in partner.get((l.dst, l.src), ())
+                             if b not in used), None)
+                if back is None:
+                    continue
+                used.update((l.id, back))
+                forward.append(l.id)
+                backward.append(back)
+                if l.dst not in seen:
+                    seen.add(l.dst)
+                    stack.append(iter(self._out[l.dst]))
+                    break
+            else:
+                stack.pop()
+        first = self._extract(range(self.num_nodes), forward,
+                              f"{self.name}_orient0")
+        out = first.topology.hop_distances_np(root)
+        back = first.topology.hop_distances_to(root)
+        got = None
+        if all(out[v] >= 0 and back[v] >= 0 for v in seen):
+            got = (first, self._extract(range(self.num_nodes), backward,
+                                        f"{self.name}_orient1"))
+        views[key] = got
+        return got
+
     def __repr__(self) -> str:
         return (
             f"Topology({self.name!r}, nodes={self.num_nodes} "

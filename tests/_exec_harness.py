@@ -109,10 +109,10 @@ def assert_conformant(kind: str, got: np.ndarray, want: np.ndarray,
 
 
 def check_collective(kind: str, topo, spec, group, *, n: int = 8,
-                     seed: int = 0, program=None) -> None:
+                     seed: int = 0, program=None, payload: int = 3) -> None:
     """End-to-end: build input, execute on the mesh, compare member outputs
     against the numpy reference and non-member outputs against zeros."""
-    x = make_input(kind, group, n, seed=seed)
+    x = make_input(kind, group, n, payload=payload, seed=seed)
     got = run_on_mesh(kind, topo, spec, x, n=n, program=program)
     want = reference(kind, group, x)
     members = set(group)
@@ -160,10 +160,10 @@ def emulate_collective(kind: str, prog, plan, group, x: np.ndarray) -> np.ndarra
     n, trash = prog.num_devices, plan.num_slots
     members = list(group)
     buf = np.zeros((n, plan.buffer_slots, x[0].size // (
-        len(members) if kind == "all_reduce" else 1)), x.dtype)
+        len(prog.chunk_holders) if kind == "all_reduce" else 1)), x.dtype)
     if kind == "all_reduce":
         chunks = sorted(prog.chunk_holders)
-        parts = x.reshape(n, len(members), -1)
+        parts = x.reshape(n, len(chunks), -1)
         for d in range(n):
             for ci, c in enumerate(chunks):
                 slot = plan.slot_of.get((d, c), trash) if d in members else trash

@@ -82,14 +82,24 @@ def test_full_group_conformance(route, kind):
                      seed=hash((route, kind)) % 2**32)
 
 
-@pytest.mark.parametrize("route", ["flat_ring", "hier_grid"])
-def test_pipelined_all_reduce(route):
+@pytest.mark.parametrize("route, chunks", [
+    ("flat_ring", 1), ("hier_grid", 1), ("flat_ring", 2)],
+    ids=["flat_ring", "hier_grid", "flat_ring-split"])
+def test_pipelined_all_reduce(route, chunks):
     """The chunk-pipelined RS->AG junction (per-chunk release floors)
-    collapses to wave order at execution and stays numerically exact."""
+    collapses to wave order at execution and stays numerically exact; with
+    ``chunks=2`` each shard's halves go around the ring's two directions."""
+    from repro.comms.primitives import synthesize_program
+
     topo_name, kw = ROUTES[route]
     topo = build_topo(topo_name)
-    req = request("all_reduce", range(N), pipelined=True, **kw)
-    check_collective("all_reduce", topo, req, tuple(range(N)), n=N, seed=7)
+    req = request("all_reduce", range(N), pipelined=True, chunks=chunks,
+                  **kw)
+    program = synthesize_program(topo, req)
+    assert len(program[0].chunk_holders) == chunks * N
+    # the program's chunks * N pieces divide the N * payload columns
+    check_collective("all_reduce", topo, req, tuple(range(N)), n=N, seed=7,
+                     program=program, payload=3 * chunks)
 
 
 @pytest.mark.parametrize("kind", KINDS)

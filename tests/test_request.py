@@ -138,8 +138,11 @@ class TestLegacyShimEquivalence:
          (False, False, None), (6.25, True, (False, False, None))),
         (CollectiveRequest("reduce", (3, 1, 2), root=2, bytes=4.0),
          None, (4.0, 2)),
+        (CollectiveRequest("all_reduce", (0, 1, 2, 3), bytes=3.125,
+                           chunks=2, pipelined=True),
+         (False, False, None), (3.125, True, (False, False, None), 2)),
     ], ids=["all_gather", "all_to_all", "reduce_scatter", "all_reduce",
-            "reduce"])
+            "reduce", "all_reduce_split"])
     def test_registry_params_are_pinned(self, req, route, params):
         """The registry keys (and the on-disk cache entries written under
         them) hold these tuples; a change here orphans every cached plan."""
@@ -192,8 +195,11 @@ class TestProgramRequestPath:
         planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
                                         registry=AlgorithmRegistry())
         prog, plan = planner.program(kind, "x", 0, nbytes=nbytes)
-        req = CollectiveRequest(kind, (0, 1, 2, 3), bytes=nbytes,
-                                pipelined=kind == "all_reduce")
+        # a named all-reduce is pipelined, and a chunk of 6.25 MiB splits
+        # each shard in two (MeshCollectivePlanner's size gate)
+        split = 1 + (kind == "all_reduce")
+        req = CollectiveRequest(kind, (0, 1, 2, 3), bytes=nbytes / split,
+                                chunks=split, pipelined=kind == "all_reduce")
         prog2, plan2 = synthesize_program(planner.topo, req,
                                           registry=planner.registry)
         # one cache entry, not two equal ones

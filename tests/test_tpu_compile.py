@@ -90,15 +90,17 @@ def test_pccl_collective_compiles_to_permutes(kind, mesh):
 
 
 def test_all_reduce_slot_buffer_is_whole_tiles(mesh):
-    """One 25 MiB f32 bucket, the DDP cell's: the slot buffer holds each
-    chunk as rows of 128 lanes, no array stripes the chunk across sublanes,
-    and placement needs no relayout loop."""
+    """One 25 MiB f32 bucket, the DDP cell's, each shard split in two
+    halves: the slot buffer holds each chunk as rows of 128 lanes, no array
+    stripes the chunk across sublanes, and placement needs no relayout
+    loop."""
     bucket = 6553600
     planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
                                     registry=AlgorithmRegistry())
     program = planner.program("all_reduce", "x", 0, nbytes=bucket / 4 / 2**18)
     spec = CollectiveRequest("all_reduce", group=(0, 1, 2, 3))
-    chunk = bucket // 4
+    assert len(program[0].chunk_holders) == 8
+    chunk = bucket // 8
 
     def run(xl):
         return primitives.pccl_all_reduce(xl, "x", None, spec,
@@ -151,14 +153,16 @@ def test_tp_decode_slot_buffer_is_whole_tiles(kind, shape, mesh):
     assert " while(" not in hlo
 
 
-@pytest.mark.parametrize("shape, dtype", [((6553600,), jnp.float32),
-                                          ((32, 6144), jnp.bfloat16)],
-                         ids=["ddp-bucket-f32", "tp-decode-bf16"])
-def test_all_reduce_keeps_two_permutes_in_flight(shape, dtype, mesh):
+@pytest.mark.parametrize("shape, dtype, permutes", [
+    ((6553600,), jnp.float32, 12), ((32, 6144), jnp.bfloat16, 8)],
+    ids=["ddp-bucket-f32", "tp-decode-bf16"])
+def test_all_reduce_keeps_two_permutes_in_flight(shape, dtype, permutes,
+                                                 mesh):
     """The DDP cell's 25 MiB f32 bucket and the TP decode cell's [32, 6144]
-    bf16 activations: the 2x2 all-reduce's 8 permutes run as 4 waves of
-    2, so at 4 points of the scheduled program a permute starts before the
-    one started last is done."""
+    bf16 activations: the 2x2 all-reduce's permutes run in waves of 2 (12
+    in 6 for the bucket, its shards split in two; 8 in 4 for the decode
+    activations), so at the start of every wave's second permute the
+    first is not yet done."""
     planner = MeshCollectivePlanner(mesh2d(2, 2), {"x": 4},
                                     registry=AlgorithmRegistry())
     itemsize = jnp.dtype(dtype).itemsize
@@ -177,7 +181,7 @@ def test_all_reduce_keeps_two_permutes_in_flight(shape, dtype, mesh):
     entry = hlo[hlo.index("\nENTRY"):]  # a scheduled module lists in order
     events = re.findall(r"%collective-permute-(start|done)(?:\.\d+)? = ",
                         entry)
-    assert events.count("start") == events.count("done") == 8
+    assert events.count("start") == events.count("done") == permutes
     in_flight = overlapped = 0
     for event in events:
         if event == "start":
@@ -185,7 +189,7 @@ def test_all_reduce_keeps_two_permutes_in_flight(shape, dtype, mesh):
             in_flight += 1
         else:
             in_flight -= 1
-    assert overlapped == 4
+    assert overlapped == permutes // 2
 
 
 def test_flash_attention_compiles_natively(one_chip):
